@@ -6,8 +6,8 @@ chunnel does to the collective roofline term. Numerical equivalence of the
 transports is covered by tests/test_comm.py; wall-clock on real links is out
 of scope for the CPU container (see EXPERIMENTS.md §Roofline).
 
-Each transport compiles in its own subprocess: a 512-host-device XLA compile
-retains several GB, and the CPU container kills accumulating processes.
+Each transport compiles in its own CPU-only subprocess (512 fake host
+devices, never the chip): a 512-device XLA compile retains several GB.
 compressed_int8 (full-tree quantized all-gather) is excluded — it exceeds the
 XLA-CPU compiler's host memory at 1.2B params (§Perf refuted-hypothesis log);
 its compile-feasible form is hier_compressed (quantizes 1/16 shards).
@@ -24,10 +24,9 @@ from benchmarks.common import emit
 TRANSPORTS = ("xla", "psum", "ring", "hierarchical", "hier_compressed")
 
 _INNER = r"""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 import json, sys
-from repro.launch.dryrun import lower_cell
+from repro.launch.dryrun import lower_cell, use_fake_host_devices
+use_fake_host_devices()
 rec = lower_cell("llama3.2-1b", "train_4k", multi_pod=True, transport=sys.argv[1])
 r = rec["roofline"]
 print("RESULT " + json.dumps({
@@ -42,26 +41,30 @@ print("RESULT " + json.dumps({
 def main() -> None:
     env = dict(os.environ)
     env["PYTHONPATH"] = env.get("PYTHONPATH", "src")
+    failed = []
     for transport in TRANSPORTS:
         try:
             out = subprocess.run(
                 [sys.executable, "-c", _INNER, transport],
                 env=env, capture_output=True, text=True, timeout=1200)
-            line = next((l for l in out.stdout.splitlines()
-                         if l.startswith("RESULT ")), None)
-            if line is None:
-                emit(f"collectives_{transport}", 0.0,
-                     f"failed:rc={out.returncode}")
-                continue
-            r = json.loads(line[len("RESULT "):])
-            emit(f"collectives_{transport}", r["collective_s"] * 1e6,
-                 f"dcn_GB={r['dcn']/1e9:.3f};total_GB={r['total']/1e9:.2f};"
-                 f"dom={r['dom']}")
-        except Exception as e:
-            emit(f"collectives_{transport}", 0.0, f"failed:{type(e).__name__}")
+        except subprocess.TimeoutExpired:
+            failed.append(f"{transport}: timeout")
+            continue
+        line = next((l for l in out.stdout.splitlines()
+                     if l.startswith("RESULT ")), None)
+        if line is None:
+            failed.append(f"{transport}: rc={out.returncode} "
+                          f"{out.stderr.strip().splitlines()[-1:]}")
+            continue
+        r = json.loads(line[len("RESULT "):])
+        emit(f"collectives_{transport}", r["collective_s"] * 1e6,
+             f"dcn_GB={r['dcn']/1e9:.3f};total_GB={r['total']/1e9:.2f};"
+             f"dom={r['dom']}")
     # psum/ring over pod hit an XLA-CPU SPMD partitioner assertion
     # (spmd_partitioner_util.cc:504) on the 3-axis production mesh; they work
     # on 2-axis meshes (tests/test_substrate.py, examples/train_reconfigure.py)
+    if failed:
+        raise SystemExit("bench_collectives: " + "; ".join(failed))
 
 
 if __name__ == "__main__":

@@ -7,9 +7,8 @@ import numpy as np
 
 
 def bench_mesh(shape=(2, 4), axes=("pod", "data")):
-    """Benchmark meshes share the compat-backed test-mesh builder so the
-    harness runs on every supported JAX (0.4.x cannot type mesh axes
-    natively) and cannot diverge from the test tier."""
+    """Benchmark meshes share the test-mesh builder (Auto axes, through
+    repro.compat) so they cannot diverge from the test tier."""
     from repro.launch.mesh import make_test_mesh
 
     return make_test_mesh(shape, axes)

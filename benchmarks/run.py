@@ -15,9 +15,9 @@ from __future__ import annotations
 
 import argparse
 import importlib
+import subprocess
 import sys
 import time
-import traceback
 
 MODULES = [
     "benchmarks.bench_dataplane",
@@ -143,18 +143,17 @@ def main() -> None:
     if args.smoke:
         smoke()
         return
-    print("name,us_per_call,derived")
+    print("name,us_per_call,derived", flush=True)
     failures = 0
+    # One process per module, and none of JAX here: a chip belongs to one
+    # process at a time, so a parent holding it would starve the modules.
     for mod_name in MODULES:
         t0 = time.time()
-        try:
-            mod = __import__(mod_name, fromlist=["main"])
-            mod.main()
-            print(f"# {mod_name} done in {time.time()-t0:.1f}s", file=sys.stderr)
-        except Exception as e:
+        rc = subprocess.run([sys.executable, "-m", mod_name]).returncode
+        if rc:
             failures += 1
-            print(f"{mod_name}_FAILED,0,{type(e).__name__}: {e}")
-            traceback.print_exc(limit=5, file=sys.stderr)
+            print(f"{mod_name}_FAILED,0,rc={rc}", flush=True)
+        print(f"# {mod_name} done in {time.time()-t0:.1f}s", file=sys.stderr)
     if failures:
         raise SystemExit(f"{failures} benchmark modules failed")
 

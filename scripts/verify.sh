@@ -1,6 +1,6 @@
 #!/usr/bin/env bash
 # One-stop verification entrypoint (CI + pre-PR):
-#   1. compat feature report  — fails if the compat layer cannot bind on this JAX
+#   1. JAX report             — JAX version, backend and devices (repro.compat)
 #   2. static lint            — repro.lint --strict: stack verification,
 #                               concurrency analysis, compat-boundary + hygiene
 #                               over src/repro (docs/architecture.md §7)
@@ -27,6 +27,9 @@
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# the tests and smokes run on the CPU (Pallas kernels interpreted); the chip
+# path is chip_smoke.py
+export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 echo "== repro.compat report =="
 python -m repro.compat

@@ -1,4 +1,4 @@
-"""Three-term roofline from the dry-run artifacts (TPU v5e target).
+"""Three-term roofline from the dry-run artifacts, for a named device kind.
 
   compute    = FLOPs_per_device / peak_bf16
   memory     = HBM_bytes_per_device / hbm_bw
@@ -19,7 +19,7 @@ from typing import Optional
 
 from repro.analysis import flops as F
 from repro.analysis import hloparse
-from repro.launch.mesh import HW
+from repro.launch.mesh import peaks
 
 
 @dataclass
@@ -81,8 +81,12 @@ def analyze(
     shape,
     mesh_shape: dict,
     *,
+    device_kind: str,
     extra_collective_bytes: float = 0.0,
 ) -> Roofline:
+    """Roofline of one step on chips of ``device_kind`` (see
+    ``repro.launch.mesh.PEAKS``; an unknown kind raises)."""
+    hw = peaks(device_kind)
     n_chips = 1
     for v in mesh_shape.values():
         n_chips *= v
@@ -93,13 +97,13 @@ def analyze(
     ici, dcn, _ = _split_ici_dcn(hlo, pod_chips if mesh_shape.get("pod", 1) > 1 else 0)
     ici += extra_collective_bytes
 
-    compute_s = fpd / HW["peak_flops_bf16"]
-    memory_s = bpd / HW["hbm_bw"]
-    collective_s = ici / (HW["ici_links"] * HW["ici_link_bw"]) + dcn / HW["dcn_bw"]
+    compute_s = fpd / hw["peak_flops_bf16"]
+    memory_s = bpd / hw["hbm_bw"]
+    collective_s = ici / (hw["ici_links"] * hw["ici_link_bw"]) + dcn / hw["dcn_bw"]
     terms = {"compute": compute_s, "memory": memory_s, "collective": collective_s}
     dominant = max(terms, key=terms.get)
     step = max(terms.values())
-    mfu = cost.model_flops / (n_chips * HW["peak_flops_bf16"] * step) if step > 0 else 0.0
+    mfu = cost.model_flops / (n_chips * hw["peak_flops_bf16"] * step) if step > 0 else 0.0
     return Roofline(
         compute_s=compute_s,
         memory_s=memory_s,

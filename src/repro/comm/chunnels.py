@@ -369,7 +369,9 @@ class GradCompressed(StepChunnel):
     def init_state(self, grads_shape):
         if not self.error_feedback:
             return ()
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, jnp.float32), grads_shape)
+        # shapes only: the trainer zero-fills them where the state is placed
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32),
+                            grads_shape)
 
     def apply(self, tree, state, ctx):
         n = ctx["mesh"].shape[self.axis]

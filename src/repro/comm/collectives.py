@@ -119,16 +119,23 @@ def hierarchical_tree(tree, fast_axis: str, slow_axis: str):
 
     Balances DCN traffic: every chip moves only its 1/|fast| gradient shard
     across the slow tier instead of the full tree.
+
+    The scatter and gather are tiled over the flat vector: the untiled form
+    on a (|fast|, n/|fast|) reshape compiles many times slower for the TPU
+    (PERF.md, PR 12 findings).
     """
     flat, shapes, treedef = _flatten(tree)
-    n_fast = compat.named_axis_size(fast_axis)
-    pad = (-flat.shape[0]) % n_fast
-    xp = jnp.pad(flat, (0, pad))
-    shard = jax.lax.psum_scatter(xp.reshape(n_fast, -1), fast_axis, scatter_dimension=0,
-                                 tiled=False)
+    shard = _scatter_fast(flat, fast_axis)
     shard = jax.lax.psum(shard, slow_axis)
-    full = jax.lax.all_gather(shard, fast_axis, axis=0, tiled=False)
-    return _unflatten(full.reshape(-1)[: flat.shape[0]], shapes, treedef, tree)
+    full = jax.lax.all_gather(shard, fast_axis, axis=0, tiled=True)
+    return _unflatten(full[: flat.shape[0]], shapes, treedef, tree)
+
+
+def _scatter_fast(flat: jnp.ndarray, fast_axis: str) -> jnp.ndarray:
+    """Reduce-scatter of a flat vector over ``fast_axis``, padded to split."""
+    pad = (-flat.shape[0]) % compat.named_axis_size(fast_axis)
+    return jax.lax.psum_scatter(jnp.pad(flat, (0, pad)), fast_axis,
+                                scatter_dimension=0, tiled=True)
 
 
 def compressed_allgather_sum(x: jnp.ndarray, axis: str, *, block: int = 256,
@@ -160,11 +167,7 @@ def hierarchical_compressed_tree(tree, fast_axis: str, slow_axis: str, *, block:
                                  use_kernel: bool = False):
     """Beyond-paper combination: RS(fast) -> compressed AR(slow) -> AG(fast)."""
     flat, shapes, treedef = _flatten(tree)
-    n_fast = compat.named_axis_size(fast_axis)
-    pad = (-flat.shape[0]) % n_fast
-    xp = jnp.pad(flat, (0, pad))
-    shard = jax.lax.psum_scatter(xp.reshape(n_fast, -1), fast_axis, scatter_dimension=0,
-                                 tiled=False)
+    shard = _scatter_fast(flat, fast_axis)
     shard = compressed_allgather_sum(shard, slow_axis, block=block, use_kernel=use_kernel)
-    full = jax.lax.all_gather(shard, fast_axis, axis=0, tiled=False)
-    return _unflatten(full.reshape(-1)[: flat.shape[0]], shapes, treedef, tree)
+    full = jax.lax.all_gather(shard, fast_axis, axis=0, tiled=True)
+    return _unflatten(full[: flat.shape[0]], shapes, treedef, tree)

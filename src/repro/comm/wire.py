@@ -8,9 +8,9 @@ quantize → pack-to-bytes (int8 payload + bitcast fp32 scales into a single
 uint8 vector); the receive side runs the inverse unpack → dequantize in one
 program and splits back into per-message arrays. ``use_kernel=True`` routes
 the quantize/dequantize through the Pallas TPU kernels in
-``repro.kernels.quantize`` (interpret mode off-TPU); ``use_kernel=False`` is
-the pure-jnp oracle — tier-1 tests assert the two produce identical wire
-bytes in interpret mode.
+``repro.kernels.quantize`` (compiled on TPU, interpreted on the CPU platform);
+``use_kernel=False`` is the pure-jnp oracle — tier-1 tests assert the two
+produce identical wire bytes in interpret mode.
 """
 from __future__ import annotations
 
@@ -30,7 +30,6 @@ from repro.comm.compress import int8_wire_ratio
 from repro.core.capability import CapabilitySet
 from repro.core.chunnel import Chunnel, Datapath, WireType
 from repro.core.cost import CostModel
-from repro.kernels.quantize.ops import INTERPRET
 from repro.kernels.quantize.quantize import dequantize_blocks, quantize_blocks
 from repro.obs.trace import TRACER
 
@@ -48,12 +47,14 @@ def _next_blob_id() -> int:
         return next(_BLOB_IDS)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "use_kernel"))
-def _fused_encode(x2d: jnp.ndarray, *, block: int, use_kernel: bool) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("block", "use_kernel", "interpret"))
+def _fused_encode(x2d: jnp.ndarray, *, block: int, use_kernel: bool,
+                  interpret: Optional[bool] = None) -> jnp.ndarray:
     """(n_blocks, block) f32 -> one uint8 vector: int8 payload then bitcast
-    fp32 scales. One device program for the whole batch."""
+    fp32 scales. One device program for the whole batch. ``interpret`` is the
+    kernel's (see ``repro.kernels.interpret_mode``)."""
     if use_kernel:
-        q, s = quantize_blocks(x2d, block=block, interpret=INTERPRET)
+        q, s = quantize_blocks(x2d, block=block, interpret=interpret)
     else:
         amax = jnp.max(jnp.abs(x2d), axis=1)
         s = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
@@ -63,9 +64,10 @@ def _fused_encode(x2d: jnp.ndarray, *, block: int, use_kernel: bool) -> jnp.ndar
     return jnp.concatenate([qb, sb])
 
 
-@functools.partial(jax.jit, static_argnames=("n_blocks", "block", "use_kernel"))
+@functools.partial(jax.jit,
+                   static_argnames=("n_blocks", "block", "use_kernel", "interpret"))
 def _fused_decode(packed: jnp.ndarray, *, n_blocks: int, block: int,
-                  use_kernel: bool) -> jnp.ndarray:
+                  use_kernel: bool, interpret: Optional[bool] = None) -> jnp.ndarray:
     """Inverse of ``_fused_encode``: uint8 vector -> flat f32 of length
     n_blocks * block, again one device program."""
     qb = packed[: n_blocks * block].reshape(n_blocks, block)
@@ -73,7 +75,7 @@ def _fused_decode(packed: jnp.ndarray, *, n_blocks: int, block: int,
     sb = packed[n_blocks * block:].reshape(n_blocks, 4)
     s = jax.lax.bitcast_convert_type(sb, jnp.float32)
     if use_kernel:
-        out = dequantize_blocks(q, s, block=block, interpret=INTERPRET)
+        out = dequantize_blocks(q, s, block=block, interpret=interpret)
     else:
         out = q.astype(jnp.float32) * s[:, None]
     return out.reshape(-1)
@@ -195,8 +197,8 @@ class Reassembler:
 @dataclass
 class CompressChunnel(Chunnel):
     """Host-plane int8 compressed wire format (exact-match capability: every
-    peer must speak it). ``use_kernel=True`` is the Pallas path (interpret
-    mode off-TPU); ``False`` the jnp oracle — same bytes either way."""
+    peer must speak it). ``use_kernel=True`` is the Pallas path (interpreted
+    on the CPU platform); ``False`` the jnp oracle — same bytes either way."""
 
     block: int = 256
     use_kernel: bool = True

@@ -180,8 +180,7 @@ class LinkBandwidthSignal(SignalSource):
                 self._bytes_per_s = float(self.probe())
                 self.probes += 1
             except Exception as e:
-                # the compat probe pattern (jaxapi._warn_probe_once): a
-                # failed probe is logged at DEBUG, never swallowed silently.
+                # a failed probe is logged at DEBUG, never swallowed silently.
                 # With a cached measurement we keep serving it; without one
                 # the typed error below tells the aggregator why.
                 log.debug("link bandwidth probe failed: %s", e)

@@ -17,11 +17,14 @@ double buffering within the ~16 MB budget.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -86,7 +89,7 @@ def flash_attention(
     window=None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     B, Sq, H, hd = q.shape
     Skv, KH = k.shape[1], k.shape[2]
@@ -122,6 +125,6 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)[:, :Sq]

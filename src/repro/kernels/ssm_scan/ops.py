@@ -1,12 +1,8 @@
-"""jit'd public wrapper; interpret on CPU, compiled Mosaic on TPU."""
+"""jit'd public wrapper; see repro.kernels for where it runs."""
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.ssm_scan.ssm_scan import ssm_scan_chunk as _scan
 
-INTERPRET = jax.default_backend() != "tpu"
-
 
 def ssm_scan_chunk(a, bx, h0):
-    return _scan(a, bx, h0, interpret=INTERPRET)
+    return _scan(a, bx, h0)

@@ -12,11 +12,14 @@ D_TILE=256, N=16 -> 4 MB) + h scratch.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 D_TILE = 256
 
@@ -36,7 +39,7 @@ def _scan_kernel(a_ref, bx_ref, h0_ref, hseq_ref, hlast_ref, h_sc, *, chunk):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssm_scan_chunk(a: jnp.ndarray, bx: jnp.ndarray, h0: jnp.ndarray, *,
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """One chunk of h_t = a_t h_{t-1} + bx_t.
 
     a, bx: (B, C, d_in, N) fp32; h0: (B, d_in, N).
@@ -71,7 +74,7 @@ def ssm_scan_chunk(a: jnp.ndarray, bx: jnp.ndarray, h0: jnp.ndarray, *,
             jax.ShapeDtypeStruct(h0.shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((tile, N), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a.astype(jnp.float32), bx.astype(jnp.float32), h0.astype(jnp.float32))
     if pad:
         h_seq = h_seq[:, :, :d_in]

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 For every (architecture x input shape) cell, lower + compile the real step
@@ -15,6 +12,7 @@ memory_analysis()/cost_analysis(), and record the roofline terms
 
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -31,6 +29,16 @@ from repro.models.registry import build
 from repro.models.sharding import kv_partition_mode
 from repro.serving import steps as serve_steps
 from repro.train import step as train_step_mod
+
+# The chip whose peaks the roofline applies (repro.launch.mesh.PEAKS).
+MODELED_KIND = "TPU v5 lite"
+
+
+def use_fake_host_devices() -> None:
+    """Lower against 512 fake CPU devices — the dry run models whole pods,
+    never the chip this process might find. Call before JAX touches a device."""
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    jax.config.update("jax_platforms", "cpu")
 
 
 def input_specs(arch: str, shape_name: str):
@@ -106,7 +114,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
     cost = compat.cost_analysis(compiled)
     hlo = compiled.as_text()
     mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-    rf = roofline.analyze(hlo, cfg, shape, mesh_shape)
+    rf = roofline.analyze(hlo, cfg, shape, mesh_shape, device_kind=MODELED_KIND)
 
     per_dev_bytes = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
                      + mem.generated_code_size_in_bytes
@@ -117,6 +125,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         "kind": shape.kind,
         "multi_pod": multi_pod,
         "mesh": mesh_shape,
+        "device_kind": MODELED_KIND,
         "transport": transport,
         "kv_partition": (kv_partition_mode(cfg, mesh, sh)
                          if shape.kind == "decode" else None),
@@ -159,6 +168,7 @@ def main() -> None:
     ap.add_argument("--out", default="results/dryrun")
     ap.add_argument("--tag", default="")
     args = ap.parse_args()
+    use_fake_host_devices()
 
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)

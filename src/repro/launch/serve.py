@@ -14,6 +14,7 @@ import numpy as np
 from repro import compat
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_test_mesh
 from repro.models import build
 
@@ -26,6 +27,7 @@ def main() -> None:
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_test_mesh((1, 1))

@@ -1,16 +1,16 @@
 """Compat-boundary rule: version-gated JAX symbols stay in src/repro/compat/.
 
-The ROADMAP rule this enforces: the repo supports JAX 0.4.37 through 0.6.x,
-and every symbol whose name/location/semantics moved across that range is
-wrapped once in ``repro.compat``. A direct use anywhere else works on the
-developer's JAX and breaks on the other floor — in CI at best, at a user's
-site at worst. The checker is import-resolution-aware: it builds the module's
+The ROADMAP rule this enforces: every JAX symbol whose name, location or
+semantics has moved between JAX releases is wrapped once in ``repro.compat``,
+so moving to a new JAX touches that one module. A direct use anywhere else
+breaks silently at the next upgrade (JAX 0.9, for one, made meshes Explicit
+by default). The checker is import-resolution-aware: it builds the module's
 alias table from its ``import``/``from`` statements and resolves dotted
 chains back to their roots, so ``from jax.experimental.shard_map import
 shard_map`` and ``import jax.experimental.shard_map as smap`` are both caught
 while ``compat.shard_map`` (the sanctioned wrapper) is not.
 
-Gated symbols (see compat/jaxapi.py for what moved where):
+Gated symbols (each has a wrapper in compat/__init__.py):
 
   shard_map            jax.experimental.shard_map -> jax.shard_map (0.6)
   AxisType             new in 0.5.x (explicit-sharding mesh axis types)
@@ -94,7 +94,7 @@ class _CompatVisitor(ast.NodeVisitor):
     def _finding(self, node: ast.AST, what: str) -> None:
         self.out.append(Finding(
             "compat-boundary", self.mod.path, node.lineno, node.col_offset,
-            f"{what} is version-gated across the supported JAX range — "
+            f"{what} is version-gated across JAX releases — "
             "go through repro.compat (ROADMAP: no file outside "
             "src/repro/compat/ touches a gated symbol)"))
 

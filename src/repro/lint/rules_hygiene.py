@@ -6,15 +6,13 @@
                    a swallowed exception turns a diagnosable fault into a
                    silent hang or a stale decision. Catching broadly is fine
                    — PROVABLY DOING SOMETHING with it (log, count, re-raise,
-                   fall back) is the requirement; see compat/jaxapi.py's
-                   ``_warn_probe_once`` for the sanctioned log-once pattern.
+                   fall back) is the requirement.
   mutable-default  ``def f(x, acc=[])`` shares one list across every call —
                    the classic aliasing bug. Use ``None`` + fill-in.
 
 Scope: these rules run only over the packages named in the scope list below.
-``src/repro/compat/`` is deliberately out of scope for silent-except: it is
-the probing layer, where a swallowed probe failure IS the documented fallback
-mechanism (each probe logs once at DEBUG through its own machinery).
+``src/repro/compat/`` is out of scope: it wraps JAX and handles no
+control-plane failures.
 """
 from __future__ import annotations
 
@@ -79,8 +77,7 @@ def check_hygiene(mod: Module) -> List[Finding]:
                 out.append(Finding(
                     "silent-except", mod.path, node.lineno, node.col_offset,
                     "except swallows every exception with no log/counter/"
-                    "re-raise — at minimum log once at DEBUG "
-                    "(compat/jaxapi.py _warn_probe_once pattern)"))
+                    "re-raise — at minimum log once at DEBUG"))
         elif isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
             defaults = list(node.args.defaults) + [
                 d for d in node.args.kw_defaults if d is not None]

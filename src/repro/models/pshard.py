@@ -22,8 +22,6 @@ def _auto_batch_axes():
     mesh = compat.current_mesh()
     if mesh is None or not getattr(mesh, "axis_names", ()):
         return None, ()
-    # compat.axis_is_auto logs a failed axis-type probe once at DEBUG
-    # instead of silently treating the axis as constrainable.
     axes = tuple(a for a in ("pod", "data")
                  if a in mesh.axis_names and compat.axis_is_auto(mesh, a))
     return mesh, axes
@@ -41,10 +39,7 @@ def shard_batch(x, dim: int = 0):
         return x
     spec = [None] * x.ndim
     spec[dim] = axes if len(axes) > 1 else axes[0]
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def shard_tree_batch(tree, dim: int = 0):
@@ -74,10 +69,7 @@ def shard_activations(x, batch_dim: int = 0, seq_dim: int = 1):
             spec[seq_dim] = "model"
     if all(a is None for a in spec):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def shard_model_dim(x, dim: int, batch_dim: int = 0):
@@ -100,10 +92,7 @@ def shard_model_dim(x, dim: int, batch_dim: int = 0):
             spec[dim] = "model"
     if all(a is None for a in spec):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def shard_heads(x, batch_dim: int = 0, head_dim: int = 2):
@@ -126,7 +115,4 @@ def shard_heads(x, batch_dim: int = 0, head_dim: int = 2):
             spec[head_dim] = "model"
     if all(a is None for a in spec):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, P(*spec))

@@ -138,9 +138,11 @@ def make_train_step(
             opt_n = narrow(opt, opt_dtypes)
             params, opt_n, comm, loss, metrics = core(
                 params, opt_n, comm, step, batch_local, 1.0)
-            loss = sum(jax.lax.pmean(loss, a) for a in manual) / len(manual)
-            metrics = {k: sum(jax.lax.pmean(v, a) for a in manual) / len(manual)
-                       for k, v in metrics.items()}
+            # mean over every manual axis at once: averaging per-axis means
+            # would weight the shards unevenly when two axes are manual
+            axes = tuple(sorted(manual))
+            loss = jax.lax.pmean(loss, axes)
+            metrics = {k: jax.lax.pmean(v, axes) for k, v in metrics.items()}
             return params, widen(opt_n), comm, loss, metrics
 
         batch_specs = jax.tree.map(lambda _: P(*(tuple(manual),)), batch)

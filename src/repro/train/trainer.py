@@ -228,9 +228,12 @@ class ReconfigurableTrainer:
     # -- step construction -------------------------------------------------------
     def _build_step(self) -> None:
         self.chunnels = self._transport_chunnels(self.transport_name)
+        # The step donates the state it is given: params, moments and the
+        # new state would not fit one chip twice. A checkpoint copies the
+        # state to the host before the next step (Checkpointer.save).
         self.jitted = step_mod.jit_train_step(
             self.model, self.tcfg, self.chunnels, self.mesh, self.sharding,
-            self.model.batch_specs(self.shape), donate=False)
+            self.model.batch_specs(self.shape))
         self.state_sh, _ = step_mod.shardings_for(
             self.model, self.mesh, self.sharding, self.chunnels)
         # The next step pays (re)compilation: that blip is reconfiguration

@@ -50,6 +50,48 @@ class TestMeshContract:
         assert ok
 
 
+class TestLaunchContract:
+    def test_compile_cache_follows_the_env_var(self, monkeypatch, tmp_path):
+        from repro.launch.cache import enable_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before  # sets nothing
+
+    def test_compile_cache_defaults_to_the_checkout(self, monkeypatch):
+        from repro.launch.cache import CHECKOUT, enable_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            path = enable_compile_cache()
+            assert path == str(CHECKOUT / ".jax_cache")
+            assert (CHECKOUT / "chip_smoke.py").exists()
+            assert jax.config.jax_compilation_cache_dir == path
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_peaks_are_keyed_by_device_kind(self):
+        from repro.launch.mesh import peaks
+
+        assert peaks("TPU v5 lite")["peak_flops_bf16"] == 197e12
+        with pytest.raises(KeyError, match="cpu"):
+            peaks("cpu")
+
+    def test_pod_mesh_spans_every_device(self):
+        from repro.launch.mesh import make_pod_mesh
+
+        n = jax.device_count()
+        if n < 2 or n % 2:
+            with pytest.raises(ValueError):
+                make_pod_mesh()
+            return
+        m = make_pod_mesh()
+        assert m.axis_names == ("pod", "data")
+        assert m.devices.shape == (2, n // 2)
+
+
 @pytest.mark.slow
 class TestOneCellCompiles:
     def test_llama_decode_cell(self, tmp_path):

@@ -40,6 +40,23 @@ from repro.kernels.ssm_scan import ops as ssm_ops
 from repro.kernels.ssm_scan.ref import ssm_scan_chunk_ref
 
 
+class TestInterpretMode:
+    @pytest.mark.parametrize("platform,expect", [("cpu", True), ("tpu", False)])
+    def test_decided_by_platform(self, monkeypatch, platform, expect):
+        from repro.kernels import interpret_mode
+
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert interpret_mode() is expect
+        assert interpret_mode(not expect) is (not expect)  # explicit wins
+
+    def test_other_platforms_raise(self, monkeypatch):
+        from repro.kernels import interpret_mode
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="gpu"):
+            interpret_mode()
+
+
 class TestQuantizeKernel:
     @pytest.mark.parametrize("n_blocks", [1, 7, 128, 300])
     @pytest.mark.parametrize("block", [64, 256])

@@ -136,6 +136,24 @@ class TestTrainer:
         # EF residual state was created for the new wire format
         assert tr.reconfig_log[-1]["committed"]
 
+    def test_two_manual_axes_report_the_global_mean_loss(self, pod_mesh):
+        """hierarchical makes pod and data manual; its step-0 loss (same init,
+        same batch) must be the global mean that xla reports."""
+        mesh = make_test_mesh((2, 4), ("pod", "data"))
+        cfg = get_smoke_config("llama3.2-1b")
+        shape = ShapeConfig("t", 32, 8, "train")
+        first = {}
+        with compat.use_mesh(mesh):
+            for t in ("xla", "hierarchical"):
+                tr = ReconfigurableTrainer(
+                    cfg, shape, mesh, tcfg=TrainConfig(warmup_steps=1, total_steps=4),
+                    transport=t, hosts=[HostSpec(0, [t, "xla"])])
+                assert tr.transport_name == t
+                state = tr.init_state(jax.random.PRNGKey(0))
+                _, hist = tr.run(state, batches_for(cfg, shape), 1)
+                first[t] = hist[0]["loss"]
+        np.testing.assert_allclose(first["hierarchical"], first["xla"], rtol=1e-5)
+
     def test_straggler_triggers_reconfiguration(self, pod_mesh):
         tr, cfg, shape = self._trainer(pod_mesh, transport="psum")
         gen = batches_for(cfg, shape)
