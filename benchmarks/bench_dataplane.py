@@ -60,8 +60,7 @@ class _LegacyFabric:
         self._eps: Dict[str, _LegacyEndpoint] = {}
         self._rng = random.Random(seed)
         self._lock = threading.Lock()
-        # internal tally emulating the legacy fabric's per-send accounting
-        # cost (NOT the deprecated Fabric.sent_msgs/sent_bytes aliases)
+        # internal tally emulating the legacy fabric's per-send accounting cost
         self.byte_count = 0
         self.msg_count = 0
 

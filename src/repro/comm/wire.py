@@ -106,43 +106,63 @@ def chunk_payload(payload: bytes, hdr: dict, *,
 def encode_batch(msgs: List[Any], *, block: int = 256, use_kernel: bool = True,
                  chunk_bytes: int = 1 << 16) -> List[dict]:
     """Batch of float arrays -> wire frames. One host concat, one fused
-    device call, then chunking into ``chunk_bytes`` fabric frames."""
-    arrs = [np.asarray(m, dtype=np.float32) for m in msgs]
-    shapes = [a.shape for a in arrs]
-    total = int(sum(a.size for a in arrs))
+    device call, then chunking into ``chunk_bytes`` fabric frames. Each step
+    is a ``wire.encode.*`` stage (``TRACER.stage``)."""
+    with TRACER.stage("wire.encode.pack"):
+        arrs = [np.asarray(m, dtype=np.float32) for m in msgs]
+        shapes = [a.shape for a in arrs]
+        total = int(sum(a.size for a in arrs))
+        if total:
+            flat = np.concatenate([a.reshape(-1) for a in arrs])
+            pad = (-total) % block
+            if pad:
+                flat = np.pad(flat, (0, pad))
+            x2d = flat.reshape(-1, block)
     if total:
-        flat = np.concatenate([a.reshape(-1) for a in arrs])
-        pad = (-total) % block
-        if pad:
-            flat = np.pad(flat, (0, pad))
-        x2d = flat.reshape(-1, block)
-        packed = _fused_encode(jnp.asarray(x2d), block=block, use_kernel=use_kernel)
-        payload = np.asarray(packed, dtype=np.uint8).tobytes()
+        with TRACER.stage("wire.encode.h2d"):
+            x = jnp.asarray(x2d)
+        with TRACER.stage("wire.encode.kernel"):
+            packed = _fused_encode(x, block=block, use_kernel=use_kernel)
+            # queue the copy back behind the program, as np.asarray alone
+            # would, so that waiting here costs no extra round trip
+            packed.copy_to_host_async()
+            packed.block_until_ready()
+        with TRACER.stage("wire.encode.d2h"):
+            payload = np.asarray(packed, dtype=np.uint8).tobytes()
         n_blocks = x2d.shape[0]
     else:
         payload = b""
         n_blocks = 0
     hdr = {"shapes": [tuple(s) for s in shapes], "block": block,
            "n_blocks": n_blocks}
-    return chunk_payload(payload, hdr, chunk_bytes=chunk_bytes)
+    with TRACER.stage("wire.encode.frame"):
+        return chunk_payload(payload, hdr, chunk_bytes=chunk_bytes)
 
 
 def decode_blob(payload: bytes, hdr: dict, *, use_kernel: bool = True) -> List[np.ndarray]:
-    """Reassembled payload + header -> the original batch (dequantized)."""
+    """Reassembled payload + header -> the original batch (dequantized), in
+    ``wire.decode.*`` stages."""
     shapes = hdr["shapes"]
     n_blocks, block = hdr["n_blocks"], hdr["block"]
     if n_blocks:
-        packed = jnp.asarray(np.frombuffer(payload, dtype=np.uint8))
-        flat = np.asarray(_fused_decode(packed, n_blocks=n_blocks, block=block,
-                                        use_kernel=use_kernel))
+        with TRACER.stage("wire.decode.h2d"):
+            packed = jnp.asarray(np.frombuffer(payload, dtype=np.uint8))
+        with TRACER.stage("wire.decode.kernel"):
+            decoded = _fused_decode(packed, n_blocks=n_blocks, block=block,
+                                    use_kernel=use_kernel)
+            decoded.copy_to_host_async()
+            decoded.block_until_ready()
+        with TRACER.stage("wire.decode.d2h"):
+            flat = np.asarray(decoded)
     else:
         flat = np.zeros((0,), dtype=np.float32)
-    out: List[np.ndarray] = []
-    off = 0
-    for shp in shapes:
-        size = int(np.prod(shp)) if shp else 1
-        out.append(flat[off:off + size].reshape(shp))
-        off += size
+    with TRACER.stage("wire.decode.split"):
+        out: List[np.ndarray] = []
+        off = 0
+        for shp in shapes:
+            size = int(np.prod(shp)) if shp else 1
+            out.append(flat[off:off + size].reshape(shp))
+            off += size
     return out
 
 
@@ -186,7 +206,8 @@ class Reassembler:
         st["chunks"][k] = frame["data"]
         if st["hdr"] is not None and len(st["chunks"]) == st["n"]:
             self._partial.pop(blob_id, None)
-            payload = b"".join(st["chunks"][i] for i in range(st["n"]))
+            with TRACER.stage("wire.decode.join"):
+                payload = b"".join(st["chunks"][i] for i in range(st["n"]))
             return payload, st["hdr"]
         return None
 
@@ -244,7 +265,8 @@ class _CompressDP(Datapath):
                               use_kernel=self.ch.use_kernel,
                               chunk_bytes=self.ch.chunk_bytes)
         if self.inner is not None:
-            self.inner.send(frames)
+            with TRACER.stage("wire.send.fabric"):
+                self.inner.send(frames)
 
     def recv(self, buf, timeout=None):
         n_out = self._drain(buf, 0)
@@ -261,13 +283,19 @@ class _CompressDP(Datapath):
                 t = deadline - time.monotonic()
                 if t <= 0:
                     break  # partial blobs are kept for the next call
-            got = self.inner.recv(tmp, t)
+            got = self._read(tmp, t)
             if not got:
                 break
             for k in range(got):  # reassemble chunked blobs
                 self._ingest(tmp[k])
             n_out = self._drain(buf, n_out)
         return n_out
+
+    def _read(self, tmp, timeout) -> int:
+        """One fabric read, up to ``len(tmp)`` frames: one stage per read,
+        never per frame."""
+        with TRACER.stage("wire.recv.fabric"):
+            return self.inner.recv(tmp, timeout)
 
     def _ingest(self, frame) -> None:
         done = self._reasm.ingest(frame)

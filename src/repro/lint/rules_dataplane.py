@@ -1,10 +1,11 @@
 """Data-plane rules: keep Datapath/Fabric hot paths batched.
 
-  span-in-hot-loop       span creation (``.span``/``.begin_span``) inside a
-                         loop of a hot-path method. Tracing the data plane is
-                         batch-granular by design (``TRACER.record_batch`` is
-                         one tuple append); a Span per message would blow the
-                         <10% enabled-tracing budget bench_overhead gates.
+  span-in-hot-loop       span creation (``.span``/``.begin_span``/``.stage``)
+                         inside a loop of a hot-path method. Tracing the data
+                         plane is batch-granular by design
+                         (``TRACER.record_batch`` is one tuple append); a Span
+                         per message would blow the <10% enabled-tracing
+                         budget bench_overhead gates.
 
   per-message-hot-path   a loop (or comprehension) inside a hot-path method
                          of a Datapath/Fabric/Endpoint class performs a
@@ -102,9 +103,10 @@ def check_per_message_hot_path(mod: Module) -> List[Finding]:
     return out
 
 
-#: tracer calls that allocate a Span — forbidden per message on the data
-#: plane; ``record_batch`` (one tuple per batch) and ``.event`` stay legal
-SPAN_CTORS = {"span", "begin_span", "start_span"}
+#: tracer calls that allocate a Span or open a profiler annotation —
+#: forbidden per message on the data plane; ``record_batch`` (one tuple per
+#: batch) and ``.event`` stay legal
+SPAN_CTORS = {"span", "begin_span", "start_span", "stage"}
 
 
 def _span_calls(loop: ast.AST) -> List[ast.Call]:

@@ -19,6 +19,21 @@ Design constraints (docs/architecture.md §10):
   attributes, and nested events. Data-plane batches are 5-tuples
   ``(name, t, n, n_ok, extra)`` — one per *batch*, never per message
   (machine-enforced by the ``span-in-hot-loop`` lint rule).
+* **Stages on the device clock.** :meth:`Tracer.stage` times one stage of
+  a hot path (the wire path's copies and transfers, the trainer's input,
+  dispatch and wait), named ``<layer>.<stage>`` (``wire.encode.h2d``,
+  ``train.wait``). It always opens a JAX profiler annotation, which
+  records only while a ``jax.profiler.trace`` session runs and then lands
+  on the host plane of the trace, on the same clock as the device's ops;
+  when the tracer is enabled it also records an ordinary ring span, a
+  leaf that never becomes a parent (a wire header opened inside a stage
+  still carries the sender's span). Off,
+  a stage costs one annotation enter/exit (about a microsecond) and
+  builds no Span. Open one per batch, fabric read or train step, never
+  per message or frame (the lint rule covers ``stage`` too). To read
+  stages beside device ops, wrap a window in
+  ``jax.profiler.trace(dir)`` and open the ``.xplane.pb`` in TensorBoard's
+  profile plugin or Perfetto.
 * **Wire propagation.** ``ctx()`` returns a compact ``(trace_id,
   span_id)`` pair that rides ``ReliableChannel`` frames (``"_tc"``) and
   ``comm/wire.py`` chunk headers (``hdr["tc"]``); the receiving side
@@ -26,7 +41,8 @@ Design constraints (docs/architecture.md §10):
   endpoints and threads.
 
 Everything here is stdlib-only so any core module may import ``TRACER``
-without cycles.
+without cycles; JAX's annotation class is looked up on the first
+``stage()``, and where JAX is missing a stage records to the ring only.
 """
 from __future__ import annotations
 
@@ -34,7 +50,7 @@ import itertools
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Tuple
 
 __all__ = ["Span", "Tracer", "TRACER", "NOOP_SPAN"]
 
@@ -43,6 +59,8 @@ TraceCtx = Tuple[int, int]  # (trace_id, span_id) — the over-the-wire form
 _DEFAULT_CAPACITY = 8192  # per-thread ring depth (the flight-recorder bound)
 
 _perf = time.perf_counter  # module-global: skip the attribute walk on hot paths
+
+_UNRESOLVED = object()  # a tracer's annotation class before its first stage()
 
 
 class _NoopSpan:
@@ -136,11 +154,14 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb):
         self._tracer._pop(self)
+        self._close(exc_type, exc)
+        return False
+
+    def _close(self, exc_type, exc) -> None:
         if exc_type is not None and self.status == "ok":
             self.status = "error"
             self.attrs.setdefault("error", f"{exc_type.__name__}: {exc}")
         self.end()
-        return False
 
     def to_dict(self) -> dict:
         return {
@@ -157,6 +178,41 @@ class Span:
             "events": [{"ts": t, "name": n, "attrs": a}
                        for (t, n, a) in self.events],
         }
+
+
+class _Stage:
+    """A stage while the tracer is enabled: the profiler annotation (None
+    without JAX) around an ordinary ring span. The span is a leaf: it is
+    never pushed as a parent, so what opens inside a stage (spans, events,
+    the trace ctx a wire header carries) keeps the parent it had."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, ann, span: Span):
+        self._ann = ann
+        self._span = span
+
+    def __enter__(self) -> Span:
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self._span
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            self._span._close(exc_type, exc)
+        finally:
+            if self._ann is not None:
+                self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, or None where JAX is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class _RemoteParent:
@@ -205,6 +261,7 @@ class Tracer:
         # thread-id -> (thread-name, ring). Rings outlive their threads so
         # collect() still sees records from finished agent loops.
         self._rings: Dict[int, Tuple[str, deque]] = {}
+        self._annotation: Any = _UNRESOLVED  # see stage()
 
     # -- control plane -----------------------------------------------------
     def enable(self, capacity: Optional[int] = None) -> None:
@@ -278,6 +335,23 @@ class Tracer:
             return NOOP_SPAN
         trace_id, parent_id = self._parent(ctx)
         return Span(self, name, trace_id, next(self._ids), parent_id, attrs)
+
+    def stage(self, name: str, **attrs):
+        """One stage of a hot path, ``<layer>.<stage>``, for ``with`` use.
+
+        Always a JAX profiler annotation named ``name`` (``attrs`` become
+        its stats), so the stage sits on the device trace's clock in any
+        profiled run; when the tracer is enabled, also a ring span parented
+        as :meth:`span` parents, and never a parent itself. The ``with``
+        target is that span, or nothing to use when the tracer is off.
+        """
+        ann = self._annotation
+        if ann is _UNRESOLVED:
+            ann = self._annotation = _annotation_class()
+        if not self.enabled:
+            return NOOP_SPAN if ann is None else ann(name, **attrs)
+        return _Stage(None if ann is None else ann(name, **attrs),
+                      self.span(name, attrs or None))
 
     def begin_span(self, name: str, attrs: Optional[dict] = None,
                    ctx: Optional[TraceCtx] = None):

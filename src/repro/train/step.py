@@ -97,10 +97,14 @@ def make_train_step(
         return loss, grads
 
     def core(params, opt, comm, step, batch, pod_scale):
-        loss, grads = grads_of(params, batch)
-        grads = jax.tree.map(lambda g: g * pod_scale, grads)
-        grads, comm = apply_grad_stack(grad_chunnels, grads, comm, ctx)
-        params, opt, metrics = adamw.update(grads, opt, params, lr_fn(step), tcfg)
+        # the scopes name each device op's phase in a profiler trace
+        with jax.named_scope("grads"):
+            loss, grads = grads_of(params, batch)
+            grads = jax.tree.map(lambda g: g * pod_scale, grads)
+        with jax.named_scope("transport"):
+            grads, comm = apply_grad_stack(grad_chunnels, grads, comm, ctx)
+        with jax.named_scope("optimizer"):
+            params, opt, metrics = adamw.update(grads, opt, params, lr_fn(step), tcfg)
         return params, opt, comm, loss, metrics
 
     if not manual:

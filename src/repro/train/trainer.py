@@ -61,6 +61,7 @@ from repro.core.stack import ConcreteStack
 from repro.core.telemetry import ConnTelemetry
 from repro.core import rendezvous
 from repro.models.registry import Model, build
+from repro.obs.trace import TRACER
 from repro.train import step as step_mod
 
 
@@ -336,32 +337,37 @@ class ReconfigurableTrainer:
 
     def _run_loop(self, state, batches, num_steps, metrics_hist, ckpt_every,
                   straggler, inject_slow, controller, pod_times) -> tuple:
-        for i in range(num_steps):
-            step_idx = int(state.step)
-            batch = {k: jax.numpy.asarray(v) for k, v in batches(step_idx).items()}
+        for i in range(num_steps):  # one train.* stage of each kind a step
+            with TRACER.stage("train.input"):
+                step_idx = int(state.step)
+                batch = {k: jax.numpy.asarray(v) for k, v in batches(step_idx).items()}
             t0 = time.perf_counter()
-            state, metrics = self.jitted(state, batch)
-            jax.block_until_ready(metrics["loss"])
+            with TRACER.stage("train.dispatch"):
+                state, metrics = self.jitted(state, batch)
+            with TRACER.stage("train.wait"):
+                jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
             if inject_slow is not None:
                 extra = inject_slow(step_idx)
                 if extra > 0:
                     time.sleep(extra)
                     dt += extra
-            self.step_times.append(dt)
-            metrics_hist.append({k: float(v) for k, v in metrics.items()})
-            if ckpt_every and self.ckpt and (step_idx + 1) % ckpt_every == 0:
-                self.ckpt.save(step_idx + 1, state, asynchronous=True)
-            if straggler is not None:
-                state = self._maybe_mitigate(state, straggler)
-            if self._skip_step_telemetry:
-                self._skip_step_telemetry = False  # compile step: blip, not signal
-            else:
-                self._record_step_telemetry(dt, pod_times, step_idx)
-                if controller is not None:
-                    self._live_state = state
-                    controller.tick(self._controller_snapshot(dt))
-                    state = self._live_state  # controller_switch may have migrated it
+            with TRACER.stage("train.readback"):
+                metrics_hist.append({k: float(v) for k, v in metrics.items()})
+            with TRACER.stage("train.bookkeeping"):
+                self.step_times.append(dt)
+                if ckpt_every and self.ckpt and (step_idx + 1) % ckpt_every == 0:
+                    self.ckpt.save(step_idx + 1, state, asynchronous=True)
+                if straggler is not None:
+                    state = self._maybe_mitigate(state, straggler)
+                if self._skip_step_telemetry:
+                    self._skip_step_telemetry = False  # compile step: blip, not signal
+                else:
+                    self._record_step_telemetry(dt, pod_times, step_idx)
+                    if controller is not None:
+                        self._live_state = state
+                        controller.tick(self._controller_snapshot(dt))
+                        state = self._live_state  # controller_switch may have migrated it
         if self.ckpt:
             self.ckpt.wait()
         return state, metrics_hist

@@ -507,11 +507,6 @@ class TestBatchedFabric:
         assert 0 < c["dropped_loss"] < 200
         assert c["delivered"] == 200 - c["dropped_loss"]
         assert c["sent_bytes"] == 200 * 8 + 10 * 8
-        # legacy aliases stay wired up for older callers, but warn now
-        with pytest.warns(DeprecationWarning, match="counter alias"):
-            assert fabric.sent_msgs == c["sent"]
-        with pytest.warns(DeprecationWarning, match="counter alias"):
-            assert fabric.sent_bytes == c["sent_bytes"]
 
     def test_batch_loss_is_per_message(self):
         """One RNG draw per message within the batch mask — a lossy link

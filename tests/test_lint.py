@@ -700,6 +700,18 @@ class TestSpanInHotLoop:
     def test_batch_level_span_ok(self):
         assert lint_sources({CORE: self.GOOD_BATCH_SPAN}) == []
 
+    def test_stage_per_frame_flagged(self):
+        src = ("class ShimDP:\n"
+               "    def recv(self, buf, timeout=None):\n"
+               "        for k, frame in enumerate(self.frames):\n"
+               "            with TRACER.stage('wire.decode.join'):\n"
+               "                buf[k] = frame\n"
+               "        with TRACER.stage('wire.recv.fabric'):\n"
+               "            return self.inner.recv(buf, timeout)\n")
+        findings = lint_sources({CORE: src})
+        assert rules_of(findings) == {"span-in-hot-loop"}
+        assert [f.line for f in findings] == [4]  # the batch-level stage is legal
+
     def test_record_batch_in_loop_ok(self):
         # record_batch is the sanctioned per-batch instrument — legal even
         # inside a per-destination grouping loop

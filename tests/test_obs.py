@@ -145,6 +145,77 @@ class TestTracer:
 # ---------------------------------------------------------------------------
 
 
+class TestStage:
+    def test_stage_lands_on_the_profilers_host_plane(self, tmp_path):
+        """With the tracer off, a stage is still a profiler annotation: a
+        `jax.profiler.trace` session records it by name, its attrs as stats,
+        on a host plane."""
+        import glob
+
+        import jax
+
+        with jax.profiler.trace(str(tmp_path)):
+            with TRACER.stage("wire.encode.pack", n_blocks=3):
+                time.sleep(0.002)
+        (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+        found = [(p.name, e) for p in jax.profiler.ProfileData.from_file(path).planes
+                 for line in p.lines for e in line.events
+                 if e.name == "wire.encode.pack"]
+        assert len(found) == 1
+        plane, ev = found[0]
+        assert plane.startswith("/host:")
+        assert ev.duration_ns >= 2e6
+        assert dict(ev.stats)["n_blocks"] == 3
+        assert TRACER.collect() == []
+
+    def test_stage_records_a_ring_span_only_when_enabled(self, monkeypatch):
+        import repro.obs.trace as trace_mod
+
+        def no_span(*a, **k):
+            raise AssertionError("a disabled stage built a Span")
+
+        with monkeypatch.context() as m:
+            m.setattr(trace_mod, "Span", no_span)
+            with TRACER.stage("train.wait"):
+                pass
+        assert TRACER.collect() == []
+        TRACER.enable()
+        with TRACER.span("step") as step:
+            with TRACER.stage("train.wait", step=7) as st:
+                assert TRACER.ctx() == step.ctx  # a stage is never a parent
+        (rec,) = spans_named(TRACER.collect(), "train.wait")
+        assert rec["parent_id"] == step.span_id and st.span_id == rec["span_id"]
+        assert rec["attrs"] == {"step": 7} and rec["dur"] >= 0
+        with pytest.raises(ValueError):
+            with TRACER.stage("train.readback"):
+                raise ValueError("nan")
+        (bad,) = spans_named(TRACER.collect(), "train.readback")
+        assert bad["status"] == "error"
+
+    def test_one_wire_batch_opens_every_wire_stage_once_in_order(self):
+        from repro.comm.wire import CompressChunnel
+        from repro.core.runtime import FabricTransport
+
+        fab = Fabric()
+        a, b = fab.register("stage-tx"), fab.register("stage-rx")
+        ch = CompressChunnel(use_kernel=False)
+        tx = ch.connect_wrap(FabricTransport(a, "stage-rx").connect_wrap(None))
+        rx = ch.connect_wrap(FabricTransport(b, "stage-tx").connect_wrap(None))
+        msgs = [np.linspace(-1, 1, 1000, dtype=np.float32), np.ones((3, 7), np.float32)]
+        TRACER.enable()
+        tx.send(msgs)
+        buf = [None] * 2
+        assert rx.recv(buf, timeout=5.0) == 2
+        assert np.allclose(buf[1], 1.0)
+        names = [r["name"] for r in TRACER.collect()
+                 if r["kind"] == "span" and r["name"].startswith("wire.")]
+        assert names == [
+            "wire.encode.pack", "wire.encode.h2d", "wire.encode.kernel",
+            "wire.encode.d2h", "wire.encode.frame", "wire.send.fabric",
+            "wire.recv.fabric", "wire.decode.join", "wire.decode.h2d",
+            "wire.decode.kernel", "wire.decode.d2h", "wire.decode.split"]
+
+
 class TestReliableChannelStitching:
     def test_request_stitches_one_trace_across_endpoints(self):
         TRACER.enable()

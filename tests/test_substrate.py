@@ -122,6 +122,30 @@ class TestTrainer:
         # raise; compatible via the committed stack name check:
         assert tr.transport_name in ("compressed_int8", "psum")
 
+    def test_each_step_opens_the_five_train_stages(self, pod_mesh):
+        """Two steps record the five train.* stages twice, in step order, and
+        the step's device ops carry their phase (`jax.named_scope`)."""
+        from repro.obs.trace import TRACER
+
+        tr, cfg, shape = self._trainer(pod_mesh, transport="psum")
+        state = tr.init_state(jax.random.PRNGKey(0))
+        gen = batches_for(cfg, shape)
+        hlo = tr.jitted.lower(state, {k: jnp.asarray(v) for k, v in gen(0).items()}
+                              ).as_text(debug_info=True)
+        TRACER.reset()
+        TRACER.enable()
+        try:
+            tr.run(state, gen, 2)
+            names = [r["name"] for r in TRACER.collect()
+                     if r["kind"] == "span" and r["name"].startswith("train.")]
+        finally:
+            TRACER.disable()
+            TRACER.reset()
+        assert names == ["train.input", "train.dispatch", "train.wait",
+                         "train.readback", "train.bookkeeping"] * 2
+        for scope in ("grads/", "transport/", "optimizer/"):
+            assert scope in hlo
+
     def test_train_and_reconfigure_preserves_state(self, pod_mesh):
         tr, cfg, shape = self._trainer(pod_mesh, transport="psum")
         gen = batches_for(cfg, shape)
