@@ -2,15 +2,17 @@
 ``use_kernel=True`` real (docs/architecture.md §8, ROADMAP direction 1).
 
 The gradient-compression step chunnel models its int8 wire ratio; this module
-actually SHIPS the compressed bytes over the host fabric. The whole batch of
-float messages is flattened host-side, then one jitted device program fuses
-quantize → pack-to-bytes (int8 payload + bitcast fp32 scales into a single
-uint8 vector); the receive side runs the inverse unpack → dequantize in one
-program and splits back into per-message arrays. ``use_kernel=True`` routes
-the quantize/dequantize through the Pallas TPU kernels in
-``repro.kernels.quantize`` (compiled on TPU, interpreted on the CPU platform);
-``use_kernel=False`` is the pure-jnp oracle — tier-1 tests assert the two
-produce identical wire bytes in interpret mode.
+actually SHIPS the compressed bytes over the host fabric. A batch of float
+messages goes to the device in pieces of whole quantization blocks: a large
+message as a view of the caller's array, the small ones between joined
+host-side. The pieces are stacked on the device, then one jitted device
+program fuses quantize → pack-to-bytes (int8 payload + bitcast fp32 scales
+into a single uint8 vector); the receive side runs the inverse unpack →
+dequantize in one program and splits back into per-message arrays.
+``use_kernel=True`` routes the quantize/dequantize through the Pallas TPU
+kernels in ``repro.kernels.quantize`` (compiled on TPU, interpreted on the CPU
+platform); ``use_kernel=False`` is the pure-jnp oracle — tier-1 tests assert
+the two produce identical wire bytes in interpret mode.
 """
 from __future__ import annotations
 
@@ -40,6 +42,14 @@ BYTES = WireType.of("bytes")
 # process-global uniqueness is plenty
 _BLOB_IDS = itertools.count(1)
 _BLOB_LOCK = threading.Lock()
+
+
+# Below this many bytes a message is joined to its neighbours on the host
+# rather than uploaded as a piece of its own: where four adjacent messages
+# encode as fast joined as uploaded one by one and stacked on the device
+# (TPU v5e host: joined 0.11 ms faster at 512 KiB, 0.27 ms slower at 1 MiB;
+# PERF.md section 6).
+VIEW_MIN_BYTES = 640 << 10
 
 
 def _next_blob_id() -> int:
@@ -81,6 +91,12 @@ def _fused_decode(packed: jnp.ndarray, *, n_blocks: int, block: int,
     return out.reshape(-1)
 
 
+@jax.jit
+def _stack_rows(*pieces: jnp.ndarray) -> jnp.ndarray:
+    """The batch's (rows, block) pieces, uploaded one by one, as one array."""
+    return jnp.concatenate(pieces)
+
+
 def chunk_payload(payload: bytes, hdr: dict, *,
                   chunk_bytes: int = 1 << 16) -> List[dict]:
     """Split one blob into MTU-sized ``{"_wire": (id, k, n), "hdr", "data"}``
@@ -103,25 +119,69 @@ def chunk_payload(payload: bytes, hdr: dict, *,
             for k in range(n_chunks)]
 
 
+def plan_pieces(sizes: List[int], *, block: int,
+                min_floats: int = VIEW_MIN_BYTES // 4) -> List[range]:
+    """The pieces in which a batch of messages of ``sizes`` floats goes to the
+    device, as ranges of message indices. A message that starts on a block
+    boundary of the batch's flat stream, is whole blocks and holds at least
+    ``min_floats`` values is a piece of its own; each run of messages between
+    such is one piece, whole blocks but for the batch's tail."""
+    pieces: List[range] = []
+    start = off = 0
+    for i, n in enumerate(sizes):
+        if n >= min_floats and off % block == 0 and n % block == 0:
+            if start < i:
+                pieces.append(range(start, i))
+            pieces.append(range(i, i + 1))
+            start = i + 1
+        off += n
+    if start < len(sizes):
+        pieces.append(range(start, len(sizes)))
+    return pieces
+
+
+def _rows(run: List[np.ndarray], block: int) -> np.ndarray:
+    """One piece as (rows, block) f32: a lone whole-block message as a view
+    of it (when contiguous), else the run joined into a fresh buffer with
+    its tail zero-padded to a whole block."""
+    if len(run) == 1 and run[0].size % block == 0:
+        return run[0].reshape(-1, block)
+    n = sum(a.size for a in run)
+    flat = np.empty(-(-n // block) * block, np.float32)
+    np.concatenate([a.reshape(-1) for a in run], out=flat[:n])
+    flat[n:] = 0
+    return flat.reshape(-1, block)
+
+
+def _as_is(m: Any) -> bool:
+    """Whether ``m`` goes up with no host copy: contiguous f32 already."""
+    return isinstance(m, np.ndarray) and m.dtype == np.float32 and m.flags.c_contiguous
+
+
 def encode_batch(msgs: List[Any], *, block: int = 256, use_kernel: bool = True,
                  chunk_bytes: int = 1 << 16) -> List[dict]:
-    """Batch of float arrays -> wire frames. One host concat, one fused
-    device call, then chunking into ``chunk_bytes`` fabric frames. Each step
-    is a ``wire.encode.*`` stage (``TRACER.stage``)."""
+    """Batch of float arrays -> wire frames. The pieces of ``plan_pieces`` go
+    up one by one, are stacked on the device and encoded in one fused device
+    call; the bytes are chunked into ``chunk_bytes`` fabric frames. Each step
+    is a ``wire.encode.*`` stage (``TRACER.stage``); inside
+    ``wire.encode.pack``, ``wire.encode.views`` marks a batch that copied no
+    float on the host and ``wire.encode.concat`` one that joined, padded or
+    converted a message."""
     with TRACER.stage("wire.encode.pack"):
         arrs = [np.asarray(m, dtype=np.float32) for m in msgs]
         shapes = [a.shape for a in arrs]
-        total = int(sum(a.size for a in arrs))
-        if total:
-            flat = np.concatenate([a.reshape(-1) for a in arrs])
-            pad = (-total) % block
-            if pad:
-                flat = np.pad(flat, (0, pad))
-            x2d = flat.reshape(-1, block)
-    if total:
+        sizes = [a.size for a in arrs]
+        runs = [[arrs[i] for i in r if sizes[i]] for r in plan_pieces(sizes, block=block)]
+        runs = [run for run in runs if run]  # an empty message carries no float
+        copied = (sum(sizes) % block != 0 or any(len(run) > 1 for run in runs)
+                  or not all(map(_as_is, msgs)))
+        with TRACER.stage("wire.encode.concat" if copied else "wire.encode.views"):
+            pieces = [_rows(run, block) for run in runs]
+    if pieces:
         with TRACER.stage("wire.encode.h2d"):
-            x = jnp.asarray(x2d)
+            xs = [jnp.asarray(p) for p in pieces]
         with TRACER.stage("wire.encode.kernel"):
+            x = xs[0] if len(xs) == 1 else _stack_rows(*xs)
             packed = _fused_encode(x, block=block, use_kernel=use_kernel)
             # queue the copy back behind the program, as np.asarray alone
             # would, so that waiting here costs no extra round trip
@@ -129,7 +189,7 @@ def encode_batch(msgs: List[Any], *, block: int = 256, use_kernel: bool = True,
             packed.block_until_ready()
         with TRACER.stage("wire.encode.d2h"):
             payload = np.asarray(packed, dtype=np.uint8).tobytes()
-        n_blocks = x2d.shape[0]
+        n_blocks = x.shape[0]
     else:
         payload = b""
         n_blocks = 0

@@ -332,3 +332,101 @@ class TestFusedWire:
         tx.send(msgs)
         got = _drain(rx, 1)
         assert len(got) == 1 and got[0].shape == (500,)
+
+    @staticmethod
+    def _batch(case):
+        """Batches as the wire path meets them, at block 256; ``big`` floats
+        is the smallest message that goes up as a view of its own."""
+        from repro.comm import wire
+
+        big = wire.VIEW_MIN_BYTES // 4
+        rng = np.random.default_rng(7)
+
+        def f32(*shape):
+            return rng.standard_normal(shape).astype(np.float32) * 3.0
+
+        return {
+            "one_large": lambda: [f32(big)],
+            # the 7-message DDP bucket's shape: biases between weights
+            "large_small_large": lambda: [f32(big + 256), f32(512), f32(big), f32(512),
+                                          f32(big), f32(3584), f32(big)],
+            "two_small_adjacent": lambda: [f32(big), f32(256), f32(512), f32(big)],
+            "misaligned": lambda: [f32(33), f32(8, 9), f32(301)],
+            "large_after_misaligned": lambda: [f32(33), f32(big), f32(256)],
+            "non_contiguous": lambda: [f32(big // 128, 256)[:, ::2]],
+            "float64": lambda: [rng.standard_normal(big) * 3.0],
+            "empty": lambda: [],
+        }[case]()
+
+    CASES = {"one_large": "views", "large_small_large": "views",
+             "two_small_adjacent": "concat", "misaligned": "concat",
+             "large_after_misaligned": "concat", "non_contiguous": "concat",
+             "float64": "concat", "empty": "views"}
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_pieces_encode_to_the_host_concat_bytes(self, case):
+        """Whatever pieces a batch goes up in, its wire bytes are those of
+        one host concat, zero pad and encode of the whole batch."""
+        from repro.comm import wire
+
+        msgs = self._batch(case)
+        flat = np.concatenate([np.asarray(m, np.float32).reshape(-1) for m in msgs]
+                              + [np.zeros(0, np.float32)])
+        flat = np.pad(flat, (0, (-flat.size) % 256))
+        want = (np.asarray(wire._fused_encode(jnp.asarray(flat.reshape(-1, 256)),
+                                              block=256, use_kernel=False)).tobytes()
+                if flat.size else b"")
+        frames = wire.encode_batch(msgs, block=256, use_kernel=False)
+        assert b"".join(f["data"] for f in frames) == want
+        hdr = frames[0]["hdr"]
+        assert hdr["n_blocks"] == flat.size // 256
+        assert hdr["shapes"] == [np.shape(m) for m in msgs]
+
+    def test_pieces_encode_to_the_kernel_bytes_too(self):
+        """The Pallas path stacks the same pieces: equal to the oracle's bytes
+        on a batch of four pieces, the last padded."""
+        from repro.comm import wire
+
+        big = wire.VIEW_MIN_BYTES // 4
+        rng = np.random.default_rng(8)
+        msgs = [rng.standard_normal(n).astype(np.float32) for n in (big, 512, big, 7)]
+        assert len(wire.plan_pieces([m.size for m in msgs], block=256)) == 4
+        fk = wire.encode_batch(msgs, block=256, use_kernel=True)
+        fo = wire.encode_batch(msgs, block=256, use_kernel=False)
+        assert b"".join(f["data"] for f in fk) == b"".join(f["data"] for f in fo)
+
+    @pytest.mark.parametrize("sizes, min_floats, want", [
+        ([1024, 256, 2048], 1024, [[0], [1], [2]]),  # a lone whole-block run
+        ([1024, 256, 256, 2048], 1024, [[0], [1, 2], [3]]),
+        ([33, 1024, 256], 1024, [[0, 1, 2]]),  # the large one starts off a boundary
+        ([1024, 1000], 1024, [[0], [1]]),  # the tail keeps its pad
+        ([300, 724, 1024], 1024, [[0, 1], [2]]),  # a run ends on a boundary
+        ([1024, 0, 1024], 1024, [[0], [1], [2]]),
+        ([], 1024, []),
+    ])
+    def test_plan_pieces(self, sizes, min_floats, want):
+        from repro.comm import wire
+
+        got = wire.plan_pieces(sizes, block=256, min_floats=min_floats)
+        assert [list(r) for r in got] == want
+
+    @pytest.mark.parametrize("case", sorted(CASES))
+    def test_batch_opens_views_or_concat_inside_pack(self, case):
+        """A batch that copies no float on the host opens `wire.encode.views`
+        inside `wire.encode.pack`; one that joins, pads or converts a message
+        opens `wire.encode.concat`."""
+        from repro.comm import wire
+        from repro.obs.trace import TRACER
+
+        TRACER.reset()
+        TRACER.enable()
+        try:
+            wire.encode_batch(self._batch(case), block=256, use_kernel=False)
+            spans = [r for r in TRACER.collect() if r["kind"] == "span"]
+        finally:
+            TRACER.disable()
+            TRACER.reset()
+        names = [r["name"] for r in spans]
+        assert names[:2] == ["wire.encode.pack", f"wire.encode.{self.CASES[case]}"]
+        pack, inner = spans[0], spans[1]
+        assert pack["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] <= pack["ts"] + pack["dur"]

@@ -210,7 +210,7 @@ class TestStage:
         names = [r["name"] for r in TRACER.collect()
                  if r["kind"] == "span" and r["name"].startswith("wire.")]
         assert names == [
-            "wire.encode.pack", "wire.encode.h2d", "wire.encode.kernel",
+            "wire.encode.pack", "wire.encode.concat", "wire.encode.h2d", "wire.encode.kernel",
             "wire.encode.d2h", "wire.encode.frame", "wire.send.fabric",
             "wire.recv.fabric", "wire.decode.join", "wire.decode.h2d",
             "wire.decode.kernel", "wire.decode.d2h", "wire.decode.split"]
