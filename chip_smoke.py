@@ -31,8 +31,8 @@ ARCH = "llama3.2-1b"
 # CLI warms up over 10).
 ONE_CHIP = dict(steps=10, seq=256, batch=2, warmup=3)
 # Four chips: batch 4 splits over pod=2 x data=2. Depth is cut to 4 layers:
-# ring, hierarchical and compressed_int8 flatten the whole gradient tree, and
-# hierarchical keeps every parameter on every chip; at 8 layers hierarchical
+# ring and hierarchical flatten the whole gradient tree, and hierarchical
+# keeps every parameter on every chip; at 8 layers hierarchical
 # counts 15.86 GiB of 15.75 against a described v5e, at 4 layers 11.82.
 FOUR_CHIP = dict(steps=6, seq=256, batch=4, warmup=3, layers=4)
 SWITCH_AT = 3  # the ring -> compressed_int8 switch comes after this many steps

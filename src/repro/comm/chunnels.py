@@ -345,7 +345,6 @@ class GradCompressed(StepChunnel):
     axis: str = "pod"
     block: int = 256
     error_feedback: bool = True
-    use_kernel: bool = False
 
     def __post_init__(self):
         self.manual_axes = (self.axis,)
@@ -374,17 +373,14 @@ class GradCompressed(StepChunnel):
                             grads_shape)
 
     def apply(self, tree, state, ctx):
-        n = ctx["mesh"].shape[self.axis]
-        if self.error_feedback and state != ():
-            tree = jax.tree.map(lambda g, r: g.astype(jnp.float32) + r, tree, state)
-        out = collectives.compressed_tree(tree, self.axis, block=self.block,
-                                          use_kernel=self.use_kernel)
-        new_state = state
-        if self.error_feedback and state != ():
-            # residual of OUR contribution (what we failed to transmit)
-            new_state = jax.tree.map(
-                lambda g: compress.quantize_error(g, block=self.block), tree)
-        return jax.tree.map(lambda g: g / n, out), new_state
+        feedback = self.error_feedback and state != ()
+        if feedback:
+            with jax.named_scope("quantize"):
+                tree = jax.tree.map(lambda g, r: g.astype(jnp.float32) + r, tree, state)
+        # the residual is what OUR codes failed to carry, from the codes sent
+        out, residual = collectives.compressed_tree_ef(
+            tree, self.axis, block=self.block, mean=True, specs=ctx.get("specs"))
+        return out, (residual if feedback else state)
 
 
 @dataclass
@@ -394,7 +390,6 @@ class GradHierCompressed(StepChunnel):
     fast_axis: str = "data"
     slow_axis: str = "pod"
     block: int = 256
-    use_kernel: bool = False
 
     def __post_init__(self):
         self.manual_axes = (self.fast_axis, self.slow_axis)
@@ -421,8 +416,7 @@ class GradHierCompressed(StepChunnel):
     def apply(self, tree, state, ctx):
         n = ctx["mesh"].shape[self.slow_axis] * ctx["mesh"].shape[self.fast_axis]
         out = collectives.hierarchical_compressed_tree(
-            tree, self.fast_axis, self.slow_axis, block=self.block,
-            use_kernel=self.use_kernel)
+            tree, self.fast_axis, self.slow_axis, block=self.block)
         return jax.tree.map(lambda g: g / n, out), state
 
 

@@ -23,7 +23,7 @@ def smoke_config() -> ModelConfig:
     return CONFIG.replace(
         name="mistral-nemo-12b-smoke",
         num_layers=2,
-        d_model=64,
+        d_model=80,  # 4 heads x 16 = 64: q and o rectangular, 0.8 as published
         num_heads=4,
         num_kv_heads=2,
         head_dim=16,

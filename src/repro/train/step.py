@@ -68,11 +68,14 @@ def make_train_step(
     tcfg: TrainConfig,
     grad_chunnels: Sequence[StepChunnel],
     mesh,
+    grad_specs: Any = None,
 ) -> Callable:
-    """Returns step(state, batch) -> (state, metrics)."""
+    """Returns step(state, batch) -> (state, metrics). ``grad_specs``, the
+    gradients' PartitionSpecs as the parameters are placed, lets a transport
+    work on each chip's shard (``ctx["specs"]``)."""
     lr_fn = adamw.lr_schedule(tcfg)
     manual = stack_manual_axes(grad_chunnels) & set(mesh.axis_names)
-    ctx = {"mesh": mesh}
+    ctx = {"mesh": mesh, "specs": grad_specs}
 
     def grads_of(params, batch):
         if tcfg.microbatches <= 1:
@@ -123,13 +126,18 @@ def make_train_step(
     for a in manual:
         n_manual *= mesh.shape[a]
 
+    # XLA-CPU workaround (see moe_ffn): bf16 operands crossing a
+    # partial-manual shard_map boundary crash the CPU backend under grad.
+    # On the CPU alone they cross in f32 and get their dtypes back at both
+    # edges; elsewhere the moments cross as they are held.
+    on_cpu = mesh.devices.flat[0].platform == "cpu"
+
     def step_fn(state: TrainState, batch) -> tuple:
-        # XLA-CPU workaround (see moe_ffn): bf16 operands crossing a
-        # partial-manual shard_map boundary crash the CPU backend under grad.
-        # Cross in f32 and restore the original dtypes at both edges.
         opt_dtypes = jax.tree.map(lambda a: a.dtype, state.opt)
 
         def widen(tree):
+            if not on_cpu:
+                return tree
             return jax.tree.map(
                 lambda a: a.astype(jnp.float32) if a.dtype == jnp.bfloat16 else a, tree)
 
@@ -225,7 +233,8 @@ def shardings_for(model: Model, mesh, sh: ShardingConfig, grad_chunnels=()):
 
 def jit_train_step(model, tcfg, grad_chunnels, mesh, sh: ShardingConfig,
                    batch_specs: dict, donate: bool = True):
-    step_fn = make_train_step(model, tcfg, grad_chunnels, mesh)
+    step_fn = make_train_step(model, tcfg, grad_chunnels, mesh,
+                              grad_specs=model.param_specs(sh))
     state_sh, batch_sh_fn = shardings_for(model, mesh, sh, grad_chunnels)
     metrics_sh = None  # let XLA pick (scalars)
     return jax.jit(

@@ -39,6 +39,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint.ckpt import Checkpointer
+from repro.comm import compress
 from repro.comm.chunnels import (
     TRANSPORTS,
     StepChunnel,
@@ -165,8 +166,10 @@ class ReconfigurableTrainer:
         self.step_times: List[float] = []
         self.reconfig_log: List[dict] = []
         self.telemetry = ConnTelemetry()
+        self._param_leaf_shapes = [
+            tuple(s.shape) for s in jax.tree.leaves(self.model.param_shapes())]
         self._param_bytes = 4 * sum(  # f32 gradient bytes per full sync
-            int(np.prod(s.shape)) for s in jax.tree.leaves(self.model.param_shapes()))
+            int(np.prod(s)) for s in self._param_leaf_shapes)
         self._live_state = None  # current TrainState while a controller drives run()
         self._fleet_pub = None   # optional fleet signal plane (attach_fleet)
         # mesh-aware cost models (ROADMAP): transport cost annotations divide
@@ -259,13 +262,15 @@ class ReconfigurableTrainer:
         """Estimated cross-pod (DCN) gradient bytes per step under the active
         transport — the byte signal the controller budgets against. Coarse on
         purpose: one all-reduce ~ one param-sized exchange per chip, scaled by
-        the transport's wire format / sync cadence."""
+        the transport's wire format / sync cadence; compressed_int8 counts its
+        wire exactly, leaf by leaf (``compress.int8_wire_bytes``)."""
         if "pod" not in self.mesh.axis_names or self.mesh.shape["pod"] < 2:
             return 0
         pb = self._param_bytes
         name = self.transport_name
         if name in ("compressed_int8",):
-            return pb // 4
+            block = next((ch.block for ch in self.chunnels if hasattr(ch, "block")), 256)
+            return sum(compress.int8_wire_bytes(s, block) for s in self._param_leaf_shapes)
         if name == "hier_compressed":
             return pb // (4 * max(self.mesh.shape.get("data", 1), 1))
         if name == "hierarchical":

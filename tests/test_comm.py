@@ -83,6 +83,113 @@ class TestCollectives:
             ref, out)
 
 
+def _half_steps(x, block):
+    """Half a quantization step of each value's block, blocks of ``block``
+    along the trailing axis (numpy)."""
+    x = np.asarray(x, np.float32).reshape(np.shape(x) or (1,))
+    w = x.shape[-1]
+    xb = np.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, (-w) % block)])
+    xb = xb.reshape(x.shape[:-1] + (-1, block))
+    amax = np.abs(xb).max(axis=-1, keepdims=True)
+    scale = np.where(amax > 0, amax / 127.0, 1.0)
+    half = np.broadcast_to(scale / 2, xb.shape).reshape(x.shape[:-1] + (-1,))[..., :w]
+    dq = (np.clip(np.round(xb / scale), -127, 127) * scale).reshape(x.shape[:-1] + (-1,))[..., :w]
+    return half, dq
+
+
+def _pod_scaled(x):
+    """Each pod contributes a different tree: pod p sends (p + 1) x."""
+    p = jax.lax.axis_index("pod").astype(jnp.float32)
+    return jax.tree.map(lambda g: g * (1.0 + p), x)
+
+
+def _replica_group_sizes(hlo: str, op: str):
+    """Group size of each ``op`` in compiled HLO text."""
+    sizes = []
+    for line in hlo.splitlines():
+        if f" {op}(" not in line or "replica_groups=" not in line:
+            continue
+        groups = line.split("replica_groups=", 1)[1]
+        if groups.startswith("{{"):
+            sizes.append(len(groups[2:groups.index("}")].split(",")))
+        else:  # iota form [groups,size]<=[...]
+            sizes.append(int(groups[1:groups.index("]")].split(",")[1]))
+    return sizes
+
+
+class TestPerLeafCompressed:
+    """compressed_tree works one leaf at a time: blocks along the trailing
+    axis, each leaf's shape and sharding kept, no joined f32 vector."""
+
+    SIZES = ((17,), (3, 5), (64,), (4, 96), (2, 3, 40))
+
+    def test_agrees_with_psum_within_half_a_step_per_block(self, mesh):
+        t = tree_of(jax.random.PRNGKey(6), self.SIZES)
+        ref = run_manual(mesh, {"pod"}, lambda x: C.psum_tree(_pod_scaled(x), "pod"), t)
+        out = run_manual(mesh, {"pod"},
+                         lambda x: C.compressed_tree(_pod_scaled(x), "pod", block=32), t)
+        for k in t:
+            assert out[k].shape == t[k].shape and out[k].dtype == t[k].dtype
+            bound = sum(_half_steps(np.asarray(t[k]) * (1 + p), 32)[0] for p in range(2))
+            err = np.abs(np.asarray(out[k]) - np.asarray(ref[k]))
+            assert (err <= bound * (1 + 1e-5) + 1e-7).all(), k
+
+    def test_keeps_each_leaf_shape_and_data_sharding(self, mesh):
+        from jax.sharding import NamedSharding
+
+        specs = {"a": P("data", None), "b": P(None, "data"), "c": P()}
+        shapes = {"a": (8, 512), "b": (3, 1024), "c": (17,)}
+        sh = {k: NamedSharding(mesh, s) for k, s in specs.items()}
+        t = {k: jax.device_put(jax.random.normal(jax.random.PRNGKey(i), shapes[k]), sh[k])
+             for i, k in enumerate(sorted(shapes))}
+        f = compat.shard_map(
+            lambda x: C.compressed_tree_ef(x, "pod", specs=specs), mesh=mesh,
+            in_specs=P(), out_specs=P(), check_vma=False, axis_names={"pod"})
+        jf = jax.jit(f, in_shardings=(sh,))
+        out, resid = jf(t)
+        for k in t:
+            for got in (out[k], resid[k]):
+                assert got.shape == shapes[k]
+                assert got.sharding.is_equivalent_to(sh[k], len(shapes[k])), k
+        # each chip sends its own shard: every all-gather spans the 2 pods only
+        hlo = jf.lower(t).compile().as_text()
+        sizes = _replica_group_sizes(hlo, "all-gather")
+        assert sizes and set(sizes) == {2}
+
+    def test_no_joined_tree_and_no_n_fold_f32_dequantize(self, mesh):
+        import re
+
+        t = tree_of(jax.random.PRNGKey(7), self.SIZES)
+        f = compat.shard_map(lambda x: C.compressed_tree(x, "pod", block=32), mesh=mesh,
+                             in_specs=P(), out_specs=P(), check_vma=False,
+                             axis_names={"pod"})
+        txt = jax.jit(f).lower(t).as_text()
+        assert "concatenate" not in txt
+        largest = max(-(-s[-1] // 32) * 32 * int(np.prod(s[:-1])) for s in self.SIZES)
+        f32 = [int(np.prod([int(d) for d in m.split("x")]))
+               for m in re.findall(r"tensor<([0-9x]+)xf32>", txt)]
+        assert f32 and max(f32) <= largest
+
+    def test_error_feedback_residual_is_from_the_codes_sent(self, mesh):
+        t = tree_of(jax.random.PRNGKey(8), self.SIZES)
+        ch = chunnels.GradCompressed(axis="pod", block=32)
+        ctx = {"mesh": mesh}
+        st = jax.tree.map(lambda g: jnp.zeros(g.shape, jnp.float32), t)
+
+        def body(x):
+            x = _pod_scaled(x)
+            out, resid = ch.apply(x, st, ctx)
+            sent = C.pmean_tree(jax.tree.map(lambda g, r: g - r, x, resid), "pod")
+            return out, resid, sent
+
+        out, resid, sent = run_manual(mesh, {"pod"}, body, t)
+        for k in t:
+            _, dq = _half_steps(t[k], 32)  # pod 0 sends x itself
+            np.testing.assert_allclose(resid[k], np.asarray(t[k]) - dq, atol=1e-6)
+            # what the transport summed is exactly what the residuals left out
+            np.testing.assert_allclose(out[k], sent[k], rtol=1e-6, atol=1e-6)
+
+
 class TestCompression:
     @pytest.mark.parametrize("shape", [(100,), (17, 3), (256,), (1, 1)])
     @pytest.mark.parametrize("block", [16, 256])

@@ -112,6 +112,23 @@ class TestTrainer:
             **kw,
         ), cfg, shape
 
+    def test_compressed_wire_bytes_are_counted_leaf_by_leaf(self, pod_mesh):
+        """compressed_int8 reports one int8 per value of each padded block of
+        256 along a leaf's trailing axis and one f32 scale per block."""
+        cfg = get_smoke_config("mistral-nemo-12b")
+        assert cfg.num_heads * cfg.head_dim_ != cfg.d_model  # rectangular q, o
+        tr = ReconfigurableTrainer(
+            cfg, ShapeConfig("t", 64, 4, "train"), pod_mesh,
+            transport="compressed_int8", hosts=[HostSpec(0, ["compressed_int8"])])
+        assert tr.transport_name == "compressed_int8"
+        # d 80, 4 x 16 query and 2 x 16 KV heads, d_ff 128, vocabulary 256,
+        # 2 layers: every trailing width fits one block, so a leaf sends one
+        # block per row
+        rows = {"embed": 256, "lm_head": 80, "final_norm": 1, "ln1": 2, "ln2": 2,
+                "wq": 2 * 80, "wk": 2 * 80, "wv": 2 * 80, "wo": 2 * 64,
+                "gate": 2 * 80, "up": 2 * 80, "down": 2 * 128}
+        assert tr._dcn_bytes_per_step() == sum(rows.values()) * (256 + 4) == 396_500
+
     def test_negotiation_picks_common_transport(self, pod_mesh):
         tr, _, _ = self._trainer(
             pod_mesh, transport="psum",
