@@ -374,12 +374,11 @@ class GradCompressed(StepChunnel):
 
     def apply(self, tree, state, ctx):
         feedback = self.error_feedback and state != ()
-        if feedback:
-            with jax.named_scope("quantize"):
-                tree = jax.tree.map(lambda g, r: g.astype(jnp.float32) + r, tree, state)
-        # the residual is what OUR codes failed to carry, from the codes sent
+        # the residual is what OUR codes failed to carry, from the codes sent;
+        # the error-feedback add happens where each leaf is quantized
         out, residual = collectives.compressed_tree_ef(
-            tree, self.axis, block=self.block, mean=True, specs=ctx.get("specs"))
+            tree, self.axis, block=self.block, mean=True, specs=ctx.get("specs"),
+            residual=state if feedback else None)
         return out, (residual if feedback else state)
 
 

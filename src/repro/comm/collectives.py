@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro import compat
 
 from repro.comm import compress
+from repro.kernels.quantize import leaf
 
 
 def dcn_bytes_factor(schedule: str, *, n_fast: int = 1, sync_every: int = 1,
@@ -140,68 +141,90 @@ def _scatter_fast(flat: jnp.ndarray, fast_axis: str) -> jnp.ndarray:
 
 
 def compressed_leaf_sum(x: jnp.ndarray, axis: str, *, block: int = 256,
-                        mean: bool = False):
+                        mean: bool = False, residual=None):
     """All-reduce of one leaf over ``axis`` in the int8 block wire format.
 
     Returns ``(sum, residual)``: the sum over ``axis`` (the mean where
-    ``mean``) in ``x``'s shape and dtype, and ``x - dq(q(x))`` of the codes
-    this rank sent, for error feedback. ``x`` is quantized in blocks of
-    ``block`` along its trailing axis, keeping its shape and so the sharding
-    of its other axes; the (int8, f32 scale per block) pair is all-gathered
-    and the n copies are dequantized into one f32 accumulator, so no f32
-    array n times the leaf's size is made. Each value of a copy is within
-    half a step (its block's scale / 2) of what was quantized.
+    ``mean``) in ``x``'s shape, and ``x - dq(q(x))`` of the codes this rank
+    sent, for error feedback. Where ``residual`` is given (f32, ``x``'s
+    shape), ``x + residual`` is what is sent, and the sum is f32. ``x`` is
+    quantized in blocks of ``block`` along its trailing axis, keeping its
+    shape and so the sharding of its other axes; the (int8, f32 scale per
+    block) pair is all-gathered and the n copies are dequantized into one
+    f32 accumulator, so no f32 array n times the leaf's size is made. Each
+    value of a copy is within half a step (its block's scale / 2) of what
+    was quantized.
+
+    A leaf whose shape the Pallas kernels take (``leaf.takes_kernel``) is
+    quantized and summed by them in its own 2-D tiling; any other (a 1-D
+    leaf, a trailing width of part blocks) goes through the jnp blocks of
+    ``compress.to_blocks``. Both give the same codes, scales and sums.
     """
+    dtype = jnp.float32 if residual is not None else x.dtype
     n = compat.named_axis_size(axis)
     if n == 1:
+        if residual is not None:
+            x = x.astype(jnp.float32) + residual
         return x, jnp.zeros(x.shape, jnp.float32)
+    kernel = leaf.takes_kernel(x.shape, block)
     with jax.named_scope("quantize"):
-        xb = compress.to_blocks(x, block)
-        q, scales = compress.quantize_blocks_int8(xb)
-        residual = compress.from_blocks(
-            xb - q.astype(jnp.float32) * scales[..., None], x.shape)
+        if kernel:  # codes (rows, width), scales (rows, nblocks)
+            q, scales, new_residual = leaf.quantize_leaf(x, residual, block=block)
+        else:  # codes (..., nblocks, block), scales (..., nblocks)
+            if residual is not None:
+                x = x.astype(jnp.float32) + residual
+            xb = compress.to_blocks(x, block)
+            q, scales = compress.quantize_blocks_int8(xb)
+            new_residual = compress.from_blocks(
+                xb - q.astype(jnp.float32) * scales[..., None], x.shape)
     with jax.named_scope("exchange"):
         q_all = jax.lax.all_gather(q, axis, axis=0, tiled=False)  # (n, ...)
         s_all = jax.lax.all_gather(scales, axis, axis=0, tiled=False)
     with jax.named_scope("dequantize"):
-        total = q_all[0].astype(jnp.float32) * s_all[0][..., None]
-        for i in range(1, n):
-            total = total + q_all[i].astype(jnp.float32) * s_all[i][..., None]
-        if mean:
-            total = total / n
-        out = compress.from_blocks(total, x.shape).astype(x.dtype)
-    return out, residual
+        if kernel:
+            out = leaf.dequantize_sum(q_all, s_all, x.shape, dtype, mean=mean, block=block)
+        else:
+            total = q_all[0].astype(jnp.float32) * s_all[0][..., None]
+            for i in range(1, n):
+                total = total + q_all[i].astype(jnp.float32) * s_all[i][..., None]
+            if mean:
+                total = total / n
+            out = compress.from_blocks(total, x.shape).astype(dtype)
+    return out, new_residual
 
 
 def compressed_tree_ef(tree, slow_axis: str, *, block: int = 256,
-                       mean: bool = False, specs=None):
+                       mean: bool = False, specs=None, residual=None):
     """``compressed_leaf_sum`` of every leaf: (summed tree, residual tree).
+    ``residual``, a tree like ``tree``, is the error feedback each leaf
+    carries in (None: none).
 
-    ``specs`` (a PartitionSpec per leaf, as the leaves are placed) runs the
-    leaves in a nested shard_map, manual over the axes they name: each chip
-    then quantizes, sends and sums its own shard. Left auto, XLA gathers a
-    sharded leaf whole over those axes before the all-gather over
-    ``slow_axis``, and every chip sends and sums all of it.
+    Where the mesh has axes that are not manual yet, the leaves run in a
+    nested shard_map, manual over them, each leaf placed as ``specs`` (a
+    PartitionSpec per leaf, as the leaves are placed; None: replicated)
+    says: each chip then quantizes, sends and sums its own shard. Left
+    auto, XLA gathers a sharded leaf whole over those axes before the
+    all-gather over ``slow_axis``, and every chip sends and sums all of it;
+    and a Mosaic kernel lowers only where every axis is manual.
     """
-    def run(leaves):
-        pairs = [compressed_leaf_sum(g, slow_axis, block=block, mean=mean)
-                 for g in leaves]
+    def run(leaves, residuals):
+        pairs = [compressed_leaf_sum(g, slow_axis, block=block, mean=mean, residual=r)
+                 for g, r in zip(leaves, residuals or [None] * len(leaves))]
         return [o for o, _ in pairs], [r for _, r in pairs]
 
     leaves, treedef = jax.tree.flatten(tree)
-    axes = set()
-    if specs is not None:
-        specs = treedef.flatten_up_to(specs)
-        for spec in specs:
-            for a in spec:
-                axes |= set(a) if isinstance(a, tuple) else ({a} if a else set())
-        axes.discard(slow_axis)
-    if axes:
-        run = compat.shard_map(run, mesh=compat.current_mesh(),
-                               in_specs=(specs,), out_specs=(specs, specs),
-                               check_vma=False, axis_names=axes)
-    out, residual = run(leaves)
-    return jax.tree.unflatten(treedef, out), jax.tree.unflatten(treedef, residual)
+    residuals = treedef.flatten_up_to(residual) if residual is not None else []
+    mesh = compat.current_mesh()
+    auto = {a for a in mesh.axis_names if compat.axis_is_auto(mesh, a)} if mesh else set()
+    if auto:
+        specs = (treedef.flatten_up_to(specs) if specs is not None
+                 else [jax.sharding.PartitionSpec()] * len(leaves))
+        run = compat.shard_map(run, mesh=mesh,
+                               in_specs=(specs, specs if residuals else []),
+                               out_specs=(specs, specs),
+                               check_vma=False, axis_names=auto | {slow_axis})
+    out, new_residual = run(leaves, residuals)
+    return jax.tree.unflatten(treedef, out), jax.tree.unflatten(treedef, new_residual)
 
 
 def compressed_tree(tree, slow_axis: str, *, block: int = 256):

@@ -1,6 +1,11 @@
 """Comm-chunnel tests: collective transports agree with psum; flash-decode
 combine agrees with the local oracle; compression round-trips."""
+import json
 import os
+import re
+import subprocess
+import sys
+from pathlib import Path
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
@@ -188,6 +193,122 @@ class TestPerLeafCompressed:
             np.testing.assert_allclose(resid[k], np.asarray(t[k]) - dq, atol=1e-6)
             # what the transport summed is exactly what the residuals left out
             np.testing.assert_allclose(out[k], sent[k], rtol=1e-6, atol=1e-6)
+
+
+STEP_LOWERING = """
+import hashlib, json
+import jax
+import numpy as np
+from jax.sharding import Mesh
+from repro import compat
+from repro.configs import get_smoke_config
+from repro.configs.base import ShapeConfig
+from repro.kernels.quantize import leaf
+from repro.train import step as step_mod
+from repro.train.trainer import HostSpec, ReconfigurableTrainer
+
+leaf.interpret_mode = lambda interpret=None: False  # Mosaic kernels, lowered for TPU
+mesh = compat.set_mesh(Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("pod", "data")))
+cfg = get_smoke_config("mistral-nemo-12b").replace(
+    d_model=512, num_heads=4, num_kv_heads=4, head_dim=128, d_ff=1024, vocab_size=512)
+tr = ReconfigurableTrainer(cfg, ShapeConfig("t", 64, 4, "train"), mesh,
+                           transport="compressed_int8", hosts=[HostSpec(0, ["compressed_int8"])])
+state = jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+                     step_mod.state_shapes(tr.model, tr.chunnels), tr.state_sh)
+text = tr.jitted.trace(state, tr.model.batch_specs(tr.shape)).lower(
+    lowering_platforms=("tpu",)).as_text()
+print(json.dumps({"sha1": hashlib.sha1(text.encode()).hexdigest(),
+                  "kernels": text.count("tpu_custom_call")}))
+"""
+
+
+def test_the_lowered_pods_step_is_the_same_in_every_process():
+    """The persistent compilation cache keys a program on its lowered text:
+    two fresh processes, with different string hashes, lower the tiny pods
+    step over compressed_int8 for TPU, with its Mosaic leaf kernels, to the
+    same text (on four CPU devices: lowering needs no TPU library)."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    runs = []
+    for hash_seed in ("1", "2"):
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src, PYTHONHASHSEED=hash_seed,
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4")
+        p = subprocess.run([sys.executable, "-c", STEP_LOWERING], capture_output=True,
+                           text=True, timeout=300, env=env)
+        assert p.returncode == 0, p.stderr[-3000:]
+        runs.append(json.loads(p.stdout.splitlines()[-1]))
+    assert runs[0]["kernels"] > 0
+    assert runs[0] == runs[1]
+
+
+class TestLeafKernelPath:
+    """A leaf whose shape the Pallas kernels take is quantized and summed by
+    them (``kernels/quantize/leaf``), with the same sums and residuals as
+    the jnp blocks; a 1-D leaf or a trailing width of part blocks keeps the
+    jnp path. Shape alone decides."""
+
+    # the mistral smoke config's leaves on the pods mesh (d 80, 4 x 16
+    # query and 2 x 16 KV heads, d_ff 128, vocabulary 256: only the head's
+    # trailing width is whole blocks), a stacked leaf of whole blocks, a
+    # layer norm stack and a width of part blocks
+    SHAPES = {"embed": (256, 80), "wq": (2, 80, 64), "wk": (2, 80, 32), "wo": (2, 64, 80),
+              "down": (2, 128, 80), "ln1": (2, 80), "final_norm": (80,),
+              "lm_head": (80, 256), "stacked": (2, 16, 512), "norms": (2, 512),
+              "wide": (8, 768), "part": (4, 300)}
+
+    @staticmethod
+    def _transport(mesh, t, r):
+        f = compat.shard_map(
+            lambda x, e: C.compressed_tree_ef(_pod_scaled(x), "pod", mean=True, residual=e),
+            mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False, axis_names={"pod"})
+        return jax.jit(f)(t, r)
+
+    @pytest.mark.parametrize("pods", [2, 4])
+    def test_kernel_and_jnp_paths_agree(self, pods, monkeypatch):
+        mesh = compat.make_mesh((pods, 8 // pods), ("pod", "data"),
+                                axis_types=(compat.AUTO,) * 2)
+        t = tree_of(jax.random.PRNGKey(11), tuple(self.SHAPES.values()))
+        r = jax.tree.map(lambda g: 0.01 * jnp.ones_like(g), t)
+        out, resid = self._transport(mesh, t, r)
+        monkeypatch.setattr(C.leaf, "takes_kernel", lambda shape, block=256: False)
+        jax.clear_caches()
+        ref_out, ref_resid = self._transport(mesh, t, r)
+        for k in t:
+            np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(ref_out[k]), k)
+            np.testing.assert_array_equal(np.asarray(resid[k]), np.asarray(ref_resid[k]), k)
+            assert out[k].dtype == jnp.float32 and out[k].shape == t[k].shape
+
+    @pytest.mark.parametrize("shape,kernels", [((5120,), 0), ((4, 80), 0),
+                                               ((4, 512), 2), ((2, 8, 512), 2)])
+    def test_only_leaves_of_whole_blocks_take_the_kernels(self, mesh, shape, kernels):
+        f = compat.shard_map(lambda x: C.compressed_leaf_sum(x, "pod")[0], mesh=mesh,
+                             in_specs=P(), out_specs=P(), check_vma=False,
+                             axis_names={"pod", "data"})
+        jaxpr = str(jax.make_jaxpr(f)(jnp.zeros(shape)))
+        assert jaxpr.count("pallas_call[") == kernels
+
+    @pytest.mark.parametrize("shape", [(4, 512), (2, 8, 768), (4, 80), (300,)])
+    def test_the_wire_carries_int8_wire_bytes(self, mesh, shape):
+        """What crosses ``pod`` (the all-gathers' operands) is one int8 a
+        value of each padded block and one f32 scale a block, on either path."""
+        f = compat.shard_map(lambda x: C.compressed_leaf_sum(x, "pod")[0], mesh=mesh,
+                             in_specs=P(), out_specs=P(), check_vma=False,
+                             axis_names={"pod", "data"})
+        jaxpr = jax.make_jaxpr(f)(jnp.zeros(shape)).jaxpr.eqns[0].params["jaxpr"]
+        sent = [v.aval for e in jaxpr.eqns if e.primitive.name == "all_gather"
+                for v in e.invars]
+        assert sum(a.size * a.dtype.itemsize for a in sent) == compress.int8_wire_bytes(shape)
+
+    def test_leaves_of_one_shape_share_a_lowering(self, mesh):
+        shapes = ((2, 8, 512), (2, 8, 512), (4, 512), (4, 512), (512,))
+        t = tree_of(jax.random.PRNGKey(12), shapes)
+        f = compat.shard_map(lambda x: C.compressed_tree_ef(x, "pod", residual=x),
+                             mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False,
+                             axis_names={"pod"})
+        txt = jax.jit(f).lower(t).as_text()
+        # one function for each distinct shape, called once for each leaf
+        for kernel in ("_quantize", "_dequantize_sum"):
+            assert len(re.findall(rf"func\.func private @{kernel}(_\d+)?\(", txt)) == 2
+            assert len(re.findall(rf"call @{kernel}(_\d+)?\(", txt)) == 4
 
 
 class TestCompression:

@@ -4,6 +4,8 @@ jnp oracles + hypothesis property tests on the invariants.
 hypothesis is an optional dev dependency (requirements-dev.txt): without it
 the property-test methods are skipped while the parametrized oracle sweeps
 still run."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -33,6 +35,8 @@ except ModuleNotFoundError:
 
 from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.ref import flash_attention_ref
+from repro.comm import compress
+from repro.kernels.quantize import leaf
 from repro.kernels.quantize import ops as q_ops
 from repro.kernels.quantize.ref import dequantize_blocks_ref, quantize_blocks_ref
 from repro.kernels.quantize.quantize import dequantize_blocks, quantize_blocks
@@ -98,6 +102,88 @@ class TestQuantizeKernel:
         q, s = quantize_blocks(x, block=64, interpret=True)
         y = dequantize_blocks(q, s, block=64, interpret=True)
         np.testing.assert_array_equal(np.asarray(y), 0.0)
+
+
+class TestLeafQuantizeKernel:
+    """The gradient transport's kernels (``kernels/quantize/leaf``) against
+    the jnp arithmetic they replace (``compress.to_blocks`` and
+    ``quantize_blocks_int8``, jitted as the step runs it): codes, scales and
+    residual bit for bit, and the n-copy sum."""
+
+    # the tiny pods step's head shard, a stacked (L, r, c) leaf, a layer
+    # norm stack, 5 and 3 blocks a row (tiles of 1 and 3 blocks), and a row
+    # count that leaves a part tile
+    SHAPES = [(40, 256), (2, 24, 512), (2, 1024), (4, 1280), (300, 768)]
+
+    @staticmethod
+    @functools.partial(jax.jit, static_argnames="feedback")
+    def _jnp_quantize(g, r, feedback=True):
+        x = g.astype(jnp.float32) + r if feedback else g
+        xb = compress.to_blocks(x, 256)
+        q, s = compress.quantize_blocks_int8(xb)
+        res = compress.from_blocks(xb - q.astype(jnp.float32) * s[..., None], x.shape)
+        rows = int(np.prod(x.shape[:-1]))
+        return q.reshape(rows, -1), s.reshape(rows, -1), res
+
+    @staticmethod
+    @functools.partial(jax.jit, static_argnames=("shape", "mean"))
+    def _jnp_sum(q_all, s_all, shape, mean):
+        n = q_all.shape[0]
+        qb = q_all.reshape((n,) + shape[:-1] + (-1, 256))
+        sb = s_all.reshape((n,) + shape[:-1] + (-1,))
+        total = qb[0].astype(jnp.float32) * sb[0][..., None]
+        for i in range(1, n):
+            total = total + qb[i].astype(jnp.float32) * sb[i][..., None]
+        if mean:
+            total = total / n
+        return compress.from_blocks(total, shape)
+
+    @staticmethod
+    def _leaf(key, shape):
+        g = jax.random.normal(key, shape) * 3.0
+        return g.at[..., :256].set(0.0)  # a whole zero block: scale 1, codes 0
+
+    @pytest.mark.parametrize("feedback", [True, False])
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_quantize_is_the_jnp_path_bit_for_bit(self, shape, feedback):
+        k1, k2 = jax.random.split(jax.random.PRNGKey(len(shape) + shape[-1]))
+        g = self._leaf(k1, shape)
+        r = 0.01 * jax.random.normal(k2, shape)
+        got = leaf.quantize_leaf(g, r if feedback else None)
+        want = self._jnp_quantize(g, r, feedback=feedback)
+        assert got[0].dtype == jnp.int8 and got[1].dtype == got[2].dtype == jnp.float32
+        assert got[2].shape == shape
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.parametrize("mean", [True, False])
+    @pytest.mark.parametrize("n", [2, 4])
+    @pytest.mark.parametrize("shape", SHAPES)
+    def test_dequantize_sum_is_the_jnp_path(self, shape, n, mean):
+        keys = jax.random.split(jax.random.PRNGKey(n), n)
+        copies = [leaf.quantize_leaf(self._leaf(k, shape) * (i + 1))
+                  for i, k in enumerate(keys)]
+        q_all = jnp.stack([c[0] for c in copies])
+        s_all = jnp.stack([c[1] for c in copies])
+        got = leaf.dequantize_sum(q_all, s_all, shape, mean=mean)
+        assert got.shape == shape and got.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(self._jnp_sum(q_all, s_all, shape, mean)))
+
+    @pytest.mark.parametrize("shape,takes", [
+        ((5120,), False),      # a final norm: rank 1
+        ((4, 80), False),      # a trailing width of part blocks
+        ((2, 80, 256), True),  # a stacked leaf of whole blocks
+        ((4, 5120), True),     # a layer norm stack
+        ((0, 256), False),     # nothing to send
+    ])
+    def test_shape_alone_picks_the_kernel(self, shape, takes):
+        assert leaf.takes_kernel(shape) is takes
+        assert not leaf.takes_kernel(shape, block=64)  # a block narrower than the lanes
+
+    @pytest.mark.parametrize("nblocks,per", [(1, 1), (4, 4), (10, 2), (56, 4), (5, 1), (3, 3)])
+    def test_tile_width_divides_the_row(self, nblocks, per):
+        assert leaf.blocks_per_tile(nblocks) == per
 
 
 class TestFlashAttentionKernel:
