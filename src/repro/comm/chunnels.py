@@ -3,8 +3,8 @@
 On a TPU cluster EVERY collective chunnel is multilateral: hosts must compile
 the identical SPMD program or the job deadlocks at the first mismatched
 collective — which is exactly the incompatibility Bertha's negotiation exists
-to prevent (DESIGN.md §2). The exact-match capability labels below are what
-the host agents negotiate before compiling.
+to prevent (docs/architecture.md §1). The exact-match capability labels
+below are what the host agents negotiate before compiling.
 
 The gradient-transport Select (paper Fig. 1's Kernel-vs-DPDK analogue):
 
@@ -139,8 +139,8 @@ class StepChunnel(Chunnel):
     """A chunnel applied to pytrees inside the jitted step function.
 
     connect_wrap composes at *trace time* — the compiled program carries no
-    dispatch overhead (the Rust-monomorphization property, verified in
-    benchmarks/bench_overhead.py by HLO comparison).
+    dispatch overhead (the Rust-monomorphization property, asserted by HLO
+    comparison in tests/test_comm.py::test_noop_chunnels_compile_away).
     """
 
     multilateral = True  # SPMD: all hosts must agree
@@ -300,8 +300,8 @@ class GradHierarchical(StepChunnel):
     """RS(fast/ICI) -> AR(slow/DCN) -> AG(fast): per-chip DCN bytes / |fast|.
 
     INCOMPATIBLE with FSDP over the fast axis: taking 'data' manual replicates
-    the FSDP-sharded params inside the region (measured: 2TB/device on the
-    235B cell — EXPERIMENTS.md §Perf refuted-hypothesis log). With FSDP the
+    the FSDP-sharded params inside the region (measured in the dry run,
+    ``repro.launch.dryrun``: 2TB/device on the 235B cell). With FSDP the
     XLA/psum pod transport already sends only each chip's 1/|data| gradient
     shard over DCN, i.e. FSDP+psum IS the hierarchical schedule. Negotiation
     enforces this via the layout:fsdp exact capability below.

@@ -1,8 +1,9 @@
 """Collective implementations over a mesh axis (the TPU 'transports').
 
 These are the alternative implementations behind the gradient-transport Select
-(DESIGN.md §2): all compute the same all-reduce, with different schedules and
-wire formats, hence different collective-roofline terms:
+(``comm/chunnels.py``; docs/architecture.md §8): all compute the same
+all-reduce, with different schedules and wire formats, hence different
+collective-roofline terms:
 
   psum_tree          XLA-native all-reduce (one fused AR)
   ring_tree          explicit bidirectional-ring RS+AG via ppermute
@@ -176,7 +177,7 @@ def compressed_leaf_sum(x: jnp.ndarray, axis: str, *, block: int = 256,
             xb = compress.to_blocks(x, block)
             q, scales = compress.quantize_blocks_int8(xb)
             new_residual = compress.from_blocks(
-                xb - q.astype(jnp.float32) * scales[..., None], x.shape)
+                xb - compress.dequantize_blocks_int8(q, scales), x.shape)
     with jax.named_scope("exchange"):
         q_all = jax.lax.all_gather(q, axis, axis=0, tiled=False)  # (n, ...)
         s_all = jax.lax.all_gather(scales, axis, axis=0, tiled=False)
@@ -184,9 +185,9 @@ def compressed_leaf_sum(x: jnp.ndarray, axis: str, *, block: int = 256,
         if kernel:
             out = leaf.dequantize_sum(q_all, s_all, x.shape, dtype, mean=mean, block=block)
         else:
-            total = q_all[0].astype(jnp.float32) * s_all[0][..., None]
+            total = compress.dequantize_blocks_int8(q_all[0], s_all[0])
             for i in range(1, n):
-                total = total + q_all[i].astype(jnp.float32) * s_all[i][..., None]
+                total = total + compress.dequantize_blocks_int8(q_all[i], s_all[i])
             if mean:
                 total = total / n
             out = compress.from_blocks(total, x.shape).astype(dtype)

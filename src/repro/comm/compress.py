@@ -1,12 +1,17 @@
 """Gradient wire formats: int8 block quantization (+ error feedback).
 
 This is the 'serialization chunnel' analogue (exact-match capability — every
-peer must speak the same wire format). The jnp implementation here is the
-oracle; the Pallas TPU kernel lives in kernels/quantize and is selected with
-``use_kernel=True`` on real hardware (validated in interpret mode in tests).
+peer must speak the same wire format). The format is defined once, here:
+``quantize_blocks_int8`` (scale ``amax / 127``, 1 for an all-zero block;
+codes ``clip(round(x / scale), -127, 127)``) and ``dequantize_blocks_int8``
+(``q * scale``). Every jnp path runs this pair; the Pallas kernels in
+``repro.kernels.quantize`` (``quantize.py`` for the wire, ``leaf.py`` for the
+gradient transport) compute the same arithmetic, and their tests compare
+them against it.
 """
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -29,22 +34,6 @@ def int8_wire_bytes(shape, block: int = 256) -> int:
     for s in shape[:-1]:
         blocks *= s
     return blocks * (block + 4)
-
-
-def _pad_to_block(x: jnp.ndarray, block: int) -> jnp.ndarray:
-    flat = x.reshape(-1)
-    pad = (-flat.shape[0]) % block
-    return jnp.pad(flat, (0, pad))
-
-
-def quantize_int8(x: jnp.ndarray, *, block: int = 256,
-                  use_kernel: bool = False) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x (any shape) -> (q int8 (nblocks, block), scales fp32 (nblocks,))."""
-    if use_kernel:
-        from repro.kernels.quantize import ops as qops
-
-        return qops.quantize_int8(x, block=block)
-    return quantize_blocks_int8(_pad_to_block(x, block).reshape(-1, block))
 
 
 def to_blocks(x: jnp.ndarray, block: int) -> jnp.ndarray:
@@ -74,14 +63,19 @@ def quantize_blocks_int8(xb: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return q, scales
 
 
-def dequantize_int8(q: jnp.ndarray, scales: jnp.ndarray, shape, *, block: int = 256,
-                    use_kernel: bool = False) -> jnp.ndarray:
-    if use_kernel:
-        from repro.kernels.quantize import ops as qops
+def dequantize_blocks_int8(q: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
+    """Inverse of ``quantize_blocks_int8``: (..., nblocks, block) int8 codes
+    and (..., nblocks) f32 scales -> f32 values of the codes' shape."""
+    return q.astype(jnp.float32) * scales[..., None]
 
-        return qops.dequantize_int8(q, scales, shape, block=block)
-    n = 1
-    for s in shape:
-        n *= s
-    flat = (q.astype(jnp.float32) * scales[:, None]).reshape(-1)[:n]
+
+def quantize_int8(x: jnp.ndarray, *, block: int = 256) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x (any shape), flattened -> (q int8 (nblocks, block), scales fp32
+    (nblocks,))."""
+    return quantize_blocks_int8(to_blocks(x.reshape(-1), block))
+
+
+def dequantize_int8(q: jnp.ndarray, scales: jnp.ndarray, shape) -> jnp.ndarray:
+    """Inverse of ``quantize_int8``: drop the pad and restore ``shape``."""
+    flat = from_blocks(dequantize_blocks_int8(q, scales), (math.prod(shape),))
     return flat.reshape(shape)

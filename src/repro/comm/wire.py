@@ -1,5 +1,5 @@
 """Fused compressed wire path: the host-plane chunnel that makes
-``use_kernel=True`` real (docs/architecture.md §8, ROADMAP direction 1).
+``use_kernel=True`` real (docs/architecture.md §8).
 
 The gradient-compression step chunnel models its int8 wire ratio; this module
 actually SHIPS the compressed bytes over the host fabric. A batch of float
@@ -11,8 +11,9 @@ into a single uint8 vector); the receive side runs the inverse unpack →
 dequantize in one program and splits back into per-message arrays.
 ``use_kernel=True`` routes the quantize/dequantize through the Pallas TPU
 kernels in ``repro.kernels.quantize`` (compiled on TPU, interpreted on the CPU
-platform); ``use_kernel=False`` is the pure-jnp oracle — tier-1 tests assert
-the two produce identical wire bytes in interpret mode.
+platform); ``use_kernel=False`` runs the jnp definition of the format,
+``compress.quantize_blocks_int8``/``dequantize_blocks_int8`` — tier-1 tests
+assert the two produce identical wire bytes in interpret mode.
 """
 from __future__ import annotations
 
@@ -28,7 +29,8 @@ import jax
 import numpy as np
 from jax import numpy as jnp
 
-from repro.comm.compress import int8_wire_ratio
+from repro.comm.compress import (dequantize_blocks_int8, int8_wire_ratio,
+                                 quantize_blocks_int8)
 from repro.core.capability import CapabilitySet
 from repro.core.chunnel import Chunnel, Datapath, WireType
 from repro.core.cost import CostModel
@@ -66,9 +68,7 @@ def _fused_encode(x2d: jnp.ndarray, *, block: int, use_kernel: bool,
     if use_kernel:
         q, s = quantize_blocks(x2d, block=block, interpret=interpret)
     else:
-        amax = jnp.max(jnp.abs(x2d), axis=1)
-        s = jnp.where(amax > 0, amax / 127.0, 1.0).astype(jnp.float32)
-        q = jnp.clip(jnp.round(x2d / s[:, None]), -127, 127).astype(jnp.int8)
+        q, s = quantize_blocks_int8(x2d)
     qb = jax.lax.bitcast_convert_type(q, jnp.uint8).reshape(-1)
     sb = jax.lax.bitcast_convert_type(s, jnp.uint8).reshape(-1)
     return jnp.concatenate([qb, sb])
@@ -87,7 +87,7 @@ def _fused_decode(packed: jnp.ndarray, *, n_blocks: int, block: int,
     if use_kernel:
         out = dequantize_blocks(q, s, block=block, interpret=interpret)
     else:
-        out = q.astype(jnp.float32) * s[:, None]
+        out = dequantize_blocks_int8(q, s)
     return out.reshape(-1)
 
 

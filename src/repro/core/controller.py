@@ -18,8 +18,8 @@ trainer's rendezvous transition. Two dampers prevent flapping:
   cooldown    after a committed switch no rule may fire for ``cooldown_s``
 
 Every tick appends a ``Decision`` (fired or not, with the snapshot that
-motivated it) to ``controller.decisions`` — the audit log the benchmarks emit
-as JSON.
+motivated it) to ``controller.decisions`` — the audit log; ``switch_log()``
+is its committed switches, which the tests assert on.
 
 Rule targets come in two kinds: a *concrete* target (a ConcreteStack, or a
 plain label like a trainer transport name) fires as written, while a

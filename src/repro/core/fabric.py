@@ -2,7 +2,8 @@
 
 Best-effort datagram delivery between named endpoints with configurable
 latency and loss (so the negotiation protocol's reliability layer is exercised
-for real). Used by the §7-style application benchmarks and the negotiation /
+for real). Used by the §7-style application planes (KV serving, pub/sub, the
+WAN gateway), the wire benchmark (``bench/``) and the negotiation /
 reconfiguration protocols; the tensor math itself rides the JAX mesh.
 
 The data path is batched (docs/architecture.md §8): ``Fabric.send_batch``
@@ -119,10 +120,9 @@ class Endpoint:
                 n = want
         # Record only short reads (outside the ring lock): a full read is the
         # steady state and already visible from the sender's record — skipping
-        # it keeps the enabled-tracing cost at one record per round trip
-        # (bench_overhead gates <10% at batch=64). A short read marks the tail
-        # of a burst (or starvation), which is the receiver-side event worth a
-        # timeline instant.
+        # it keeps the enabled-tracing cost at one record per round trip. A
+        # short read marks the tail of a burst (or starvation), which is the
+        # receiver-side event worth a timeline instant.
         if n < want and TRACER.enabled:
             TRACER.record_batch("fabric.recv_many", n, n)
         return n

@@ -78,7 +78,7 @@ def pick_compatible(
     server preference order, with client preference as the per-option
     tiebreak — so unannotated stacks negotiate exactly as the historical
     first-compatible rule did. ``mode="first"`` forces that legacy behavior
-    (kept for the scored-vs-first comparison in bench_reconfigure).
+    (kept for the scored-vs-first comparison in tests/test_policy.py).
 
     Returns (server_choice, client_option_index) or None when no pair is
     compatible. ``scores`` (when given a dict) is filled with the

@@ -2,8 +2,8 @@
 
 Replacing a chunnel implementation in a live connection requires a *switch
 point* after which no thread uses the old datapath or its state. Two
-coordination mechanisms, both implemented and microbenchmarked
-(benchmarks/bench_reconfigure.py ~ paper Fig. 10):
+coordination mechanisms (paper Fig. 10), both swapped under three pumping
+threads in ``tests/test_core_bertha.py::TestReconfiguration``:
 
   LockedConn   every send/recv takes a mutex; reconfigure() holds it across
                negotiation + state migration + swap. Simple; fast-path pays a

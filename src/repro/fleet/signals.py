@@ -116,9 +116,9 @@ def measure_link_bandwidth(fabric: Optional[Fabric] = None, *,
                            payload_bytes: int = 1 << 16,
                            n_msgs: int = 32,
                            timeout_s: float = 1.0) -> float:
-    """Measured bytes/s of one fabric link, from a ``bench_collectives``-style
-    micro-run: time ``n_msgs`` payloads of ``payload_bytes`` through a fresh
-    endpoint pair. On a fabric with a ``LinkModel`` this observes the modeled
+    """Measured bytes/s of one fabric link, from a micro-run: time
+    ``n_msgs`` payloads of ``payload_bytes`` through a fresh endpoint pair.
+    On a fabric with a ``LinkModel`` this observes the modeled
     latency; on the default zero-latency fabric it measures the in-process
     copy floor — either way the value orders byte-heavy options honestly,
     which is all the cost scorer needs."""
@@ -149,10 +149,9 @@ class LinkBandwidthSignal(SignalSource):
     ``repro.comm.chunnels.calibrated_objective``).
 
     The probe is a micro-run (``measure_link_bandwidth`` by default, or any
-    ``probe() -> bytes/s`` — e.g. one derived from ``bench_collectives``
-    output); it runs at most once per ``refresh_s`` and the cached value is
-    served in between, so reading this source per aggregation tick stays
-    cheap."""
+    ``probe() -> bytes/s``); it runs at most once per ``refresh_s`` and the
+    cached value is served in between, so reading this source per
+    aggregation tick stays cheap."""
 
     name = "link_bw"
 

@@ -4,8 +4,7 @@
                          inside a loop of a hot-path method. Tracing the data
                          plane is batch-granular by design
                          (``TRACER.record_batch`` is one tuple append); a Span
-                         per message would blow the <10% enabled-tracing
-                         budget bench_overhead gates.
+                         per message would undo that.
 
   per-message-hot-path   a loop (or comprehension) inside a hot-path method
                          of a Datapath/Fabric/Endpoint class performs a
@@ -121,9 +120,10 @@ def _span_calls(loop: ast.AST) -> List[ast.Call]:
 @analyzer
 def check_span_in_hot_loop(mod: Module) -> List[Finding]:
     """Observability counterpart of ``per-message-hot-path``: span objects
-    (dict attrs, event lists, stack pushes) in a per-message loop would eat
-    the <10% enabled-tracing budget ``bench_overhead`` gates. Batch-level
-    spans (outside any loop) and ``record_batch``/``event`` are fine."""
+    (dict attrs, event lists, stack pushes) in a per-message loop would cost
+    an object a message where the data plane pays one append a batch.
+    Batch-level spans (outside any loop) and ``record_batch``/``event`` are
+    fine."""
     out: List[Finding] = []
     for node in ast.walk(mod.tree):
         if not (isinstance(node, ast.ClassDef) and _is_hot_class(node)):

@@ -4,9 +4,9 @@ The tracer's per-thread rings (``deque(maxlen=capacity)``) *are* the
 bounded history — the flight recorder is the dump trigger. Two triggers
 (docs/architecture.md §10):
 
-* :meth:`FlightRecorder.capture` — context manager wrapped around chaos
+* :meth:`FlightRecorder.capture` — context manager wrapped around
   scenario assertion blocks; an ``AssertionError`` inside dumps
-  ``benchmarks/out/flightrec_<reason>.json`` and re-raises.
+  ``results/flightrec_<reason>.json`` (git-ignored) and re-raises.
 * :func:`strand_alarm` — called by ``HostAgent._resync_prepared`` when a
   2PC participant's resync keeps failing (a peer is prepared but cannot
   learn the verdict — the stranded-peer condition); dumps once per conn.
@@ -27,7 +27,7 @@ from repro.obs.trace import TRACER, Tracer
 
 __all__ = ["FlightRecorder", "RECORDER", "strand_alarm"]
 
-_DEFAULT_OUT = os.path.join("benchmarks", "out")
+_DEFAULT_OUT = "results"
 _DEFAULT_KEEP = 16
 
 

@@ -4,10 +4,10 @@ Design constraints (docs/architecture.md §10):
 
 * **Off-by-default cheap.** Every instrumentation site in the hot path is
   guarded by a single ``if TRACER.enabled:`` attribute read; the disabled
-  path allocates nothing and is gated in ``benchmarks/bench_overhead.py``
-  at <3% of a batch-send's cost. Enabled tracing must stay <10% at
-  batch=64, which is why the data plane records compact tuples
-  (:meth:`Tracer.record_batch`) instead of full spans.
+  path records nothing (``tests/test_obs.py::TestTracer::
+  test_disabled_fabric_round_trip_records_nothing``). Enabled, the data
+  plane records compact tuples (:meth:`Tracer.record_batch`) instead of
+  full spans, so a batch costs one append.
 * **Lock-free rings.** Finished records land in a per-thread
   ``deque(maxlen=...)`` reached through ``threading.local`` — appends are
   single bytecodes under the GIL, so recording never takes a lock and can
@@ -406,8 +406,8 @@ class Tracer:
 
         The ONLY sanctioned per-batch instrumentation for ``Datapath`` /
         fabric hot loops. Callers must pre-guard with ``TRACER.enabled``.
-        The TLS ring access is inlined (no ``_ring()`` call) — this method
-        sits inside the <10%-overhead budget ``bench_overhead`` gates.
+        The TLS ring access is inlined (no ``_ring()`` call): this method
+        runs once a batch on the data plane's hot path.
         """
         try:
             ring = self._tls.ring

@@ -9,11 +9,10 @@ Two routing chunnels over the host fabric:
                       right backend (extra hop + router queueing, but backends
                       can be re-provisioned without touching clients).
 
-The benchmark (benchmarks/bench_sharding.py ~ Fig. 6) measures p50/p95 latency
-vs offered load for both, and the reconfiguration between them mid-run;
 ``routing_stack()`` packages the two as a Select so a ReconfigController can
-switch them from live telemetry (benchmarks/bench_reconfigure.py closes that
-loop end-to-end).
+switch them from live telemetry: the ``kv_load_adaptive`` policy below moves a
+client to ClientShard under load and back as it drains
+(``tests/test_policy.py::TestKvLoadAdaptive``; paper Fig. 6).
 """
 from __future__ import annotations
 

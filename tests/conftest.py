@@ -31,3 +31,21 @@ def virtual_clock():
     ``ChaosInjector.start/poll`` (and ``advance`` between polls) to replay a
     chaos schedule deterministically on any CI machine."""
     return VirtualClock(0.0)
+
+
+@pytest.fixture(autouse=True, scope="module")
+def no_inherited_mesh():
+    """Each test module starts with no ambient mesh. ``compat.set_mesh`` is
+    persistent by design, and the benchmark's train driver
+    (``bench/drivers/train.py``) and a few modules here set one for the rest
+    of their process; in a one-process run of the whole suite
+    (``scripts/verify.sh``) it reached later modules' shard_maps. An
+    axis-less mesh is no mesh to ``compat.current_mesh`` and to shard_map."""
+    import jax
+    import numpy as np
+
+    from repro import compat
+
+    if compat.current_mesh() is not None:
+        compat.set_mesh(jax.sharding.Mesh(np.array(jax.devices()[0]), ()))
+    yield

@@ -450,6 +450,140 @@ class TestWanLink:
         assert payload == b"x" * 11 and hdr == {"k": 1}
 
 
+class _Region:
+    """One region's client of a ``WanGateway`` hub: the negotiated Select
+    [FastWire | WanLink] under a ``wan_region_adaptive`` controller. Each
+    tick feeds the controller the probe timeout ratio and the windowed WAN
+    go-back-N retransmits per frame."""
+
+    def __init__(self, fabric, name):
+        from repro.comm.chunnels import WanLinkChunnel  # registers the policy
+        from repro.core import (FabricTransport, LockedConn, Select,
+                                conn_controller, make_stack)
+
+        ep_fast = fabric.register(f"{name}/fastlink")
+        ep_wan = fabric.register(f"{name}/wanlink")
+        self.stack = make_stack(Select(
+            FabricTransport(ep_fast, "hub/fast", label="FastWire"),
+            WanLinkChunnel(ep_wan, "hub/wan", mtu_bytes=2048, window=8,
+                           timeout_s=0.03, retries=8)))
+        self.handle = LockedConn(self.stack.preferred())  # FastWire
+        self.ctl = conn_controller(
+            self.handle, self.stack, policy="wan_region_adaptive",
+            policy_params={"breach_timeout_ratio": 0.05,
+                           "recover_timeout_ratio": 0.01,
+                           "recover_retransmit_ratio": 0.02, "hold": 2},
+            cooldown_s=0.15)
+        self.rid = self.timeouts = self.probes = 0
+        self._prev = (None, 0, 0)  # (datapath id, retransmits, frames_sent)
+
+    def on_wan(self) -> bool:
+        return any(c.name == "WanLink" for c in self.handle.stack.chunnels)
+
+    def probe(self, timeout: float = 0.08) -> None:
+        self.rid += 1
+        self.probes += 1
+        if self.on_wan():  # delivery is confirmed by the window's acks
+            try:
+                self.handle.send([{"rid": self.rid}])
+            except TimeoutError:
+                self.timeouts += 1
+            return
+        self.handle.send([{"rid": self.rid}])  # the gateway echoes it
+        buf = [None]
+        deadline = time.monotonic() + timeout
+        while True:
+            t = deadline - time.monotonic()
+            if t <= 0 or not self.handle.recv(buf, timeout=max(t, 0.0)):
+                self.timeouts += 1
+                return
+            if isinstance(buf[0], dict) and buf[0].get("rid") == self.rid:
+                return  # stale echoes of timed-out probes are skipped
+
+    def rtx_ratio(self) -> float:
+        if not self.on_wan():
+            self._prev = (None, 0, 0)
+            return 0.0
+        s = self.handle.dp.stats()
+        prev_id, prev_rtx, prev_fr = self._prev
+        if prev_id != id(self.handle.dp):  # a fresh datapath after a swap
+            prev_rtx = prev_fr = 0
+        self._prev = (id(self.handle.dp), s["retransmits"], s["frames_sent"])
+        return (s["retransmits"] - prev_rtx) / max(1, s["frames_sent"] - prev_fr)
+
+    def tick(self):
+        snap = self.handle.telemetry.snapshot()
+        snap["link.timeout_ratio"] = self.timeouts / max(1, self.probes)
+        snap["link.retransmit_ratio"] = self.rtx_ratio()
+        self.timeouts = self.probes = 0
+        return self.ctl.tick(snap)
+
+
+class TestWanRegions:
+    def test_lossy_region_moves_to_wanlink_clean_region_stays(self, seeded_fabric):
+        """Two regions share one ``WanGateway`` hub. WAN weather (latency,
+        jitter, 25% loss) on one region's links, then a short partition on
+        top, moves that region to the compressed + reliable WAN option; the
+        clean region keeps the fast path in the same run. The WAN wire
+        carries chunked, quantized blobs, repairs loss and absorbs the
+        partition (failed sends or keepalive misses) without wedging."""
+        from repro.serving.gateway import WanGateway
+
+        fabric = seeded_fabric(seed=7, default_link=LinkModel(latency_s=0.0002))
+        gw = WanGateway(fabric, "hub")
+        wan, dcn = _Region(fabric, "wan-cli"), _Region(fabric, "dcn-cli")
+        plan = ChaosPlan(seed=7)
+        plan.degrade("wan-cli", "hub", LinkModel(latency_s=0.004, jitter_s=0.002, loss=0.25),
+                     at=0.0, label="wan-weather")
+        # pulled one tick after the WAN region adopted the WAN stack
+        plan.partition("wan-cli", "hub", on="storm", for_s=0.2, label="wan-storm")
+        inj = ChaosInjector(fabric, plan).start()
+        inj.poll()  # the weather is on before the first probe
+        blob = np.arange(64 * 257, dtype=np.float32).reshape(64, 257) - 8000.0
+        storm_at, post_storm = None, 0
+        try:
+            for tick in range(8):
+                inj.poll()
+                if storm_at == tick:
+                    inj.fire("storm")
+                for r in (wan, dcn):
+                    if r.on_wan():
+                        r.handle.dp.ping(retries=2)  # keepalive probe
+                    for _ in range(4):
+                        r.probe()
+                        inj.poll()  # autoheal mid-window
+                        time.sleep(0.004)
+                    if r.on_wan() and tick % 2 == 0:
+                        try:
+                            r.handle.send([blob])  # chunked + quantized
+                        except TimeoutError:
+                            pass  # counted in failed_sends by the datapath
+                for r in (wan, dcn):
+                    d = r.tick()
+                    if r is wan and storm_at is None and d.reason == "switched":
+                        storm_at = tick + 1
+                if storm_at is not None and tick > storm_at:
+                    post_storm += 1
+                if post_storm >= 2:
+                    break
+            link = wan.handle.dp.stats() if wan.on_wan() else {}
+        finally:
+            inj.stop()
+            gw_stats = gw.stats()
+            gw.close()
+
+        switches = [d.rule for d in wan.ctl.switch_log()]
+        assert switches and switches[0] == "lossy-wan->compressed-reliable"
+        caps = [str(c) for ch in wan.handle.stack.chunnels for c in ch.capabilities()]
+        assert "WanLink" in [c.name for c in wan.handle.stack.chunnels]
+        assert any("wan-gbn" in c for c in caps) and any("q8b" in c for c in caps)
+        assert not dcn.ctl.switch_log()
+        assert "FastWire" in [c.name for c in dcn.handle.stack.chunnels]
+        assert gw_stats["wan_blobs"] >= 1
+        assert link.get("retransmits", 0) > 0
+        assert link.get("failed_sends", 0) + link.get("keepalive_failures", 0) > 0
+
+
 # ---------------------------------------------------------------------------
 # Partition / churn regressions
 # ---------------------------------------------------------------------------

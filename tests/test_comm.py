@@ -311,13 +311,62 @@ class TestLeafKernelPath:
             assert len(re.findall(rf"call @{kernel}(_\d+)?\(", txt)) == 4
 
 
+class _NoopChunnel(chunnels.StepChunnel):
+    """Reads and forwards the tree: ``g + 0.0`` is not elided before jit,
+    and XLA then proves it the identity."""
+
+    manual_axes = ()
+
+    def init_state(self, _):
+        return ()
+
+    def apply(self, tree, state, ctx):
+        return jax.tree.map(lambda g: g + 0.0, tree), state
+
+
+def test_noop_chunnels_compile_away():
+    """Stacking is trace-time composition: a train step on the llama3.2-1b
+    smoke config that folds its gradients through ``apply_grad_stack`` with
+    0 and with 5 no-op step chunnels compiles to the same program."""
+    from repro.configs import get_smoke_config
+    from repro.configs.base import TrainConfig
+    from repro.models import build
+    from repro.optim import adamw
+
+    cfg = get_smoke_config("llama3.2-1b")
+    model = build(cfg)
+    tcfg = TrainConfig()
+    lr = adamw.lr_schedule(tcfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    opt = jax.eval_shape(adamw.init, params)
+    tokens = jax.ShapeDtypeStruct((4, 64), jnp.int32)
+    batch = {"tokens": tokens, "labels": tokens}
+
+    def compiled(n_chunnels):
+        stack = tuple(_NoopChunnel() for _ in range(n_chunnels))
+
+        def step(params, opt, batch):
+            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+            grads, _ = chunnels.apply_grad_stack(stack, grads, tuple(() for _ in stack),
+                                                 {"mesh": None})
+            params, opt, _ = adamw.update(grads, opt, params, lr(opt.count), tcfg)
+            return params, opt, loss
+
+        return jax.jit(step).lower(params, opt, batch).compile().as_text()
+
+    # one call site for both, so the debug tables (which record the caller's
+    # column) match too: the texts are compared whole, metadata included
+    programs = [compiled(n) for n in (0, 5)]
+    assert programs[0] == programs[1]
+
+
 class TestCompression:
     @pytest.mark.parametrize("shape", [(100,), (17, 3), (256,), (1, 1)])
     @pytest.mark.parametrize("block", [16, 256])
     def test_roundtrip_error_bound(self, shape, block):
         x = jax.random.normal(jax.random.PRNGKey(0), shape) * 3.0
         q, s = compress.quantize_int8(x, block=block)
-        y = compress.dequantize_int8(q, s, shape, block=block)
+        y = compress.dequantize_int8(q, s, shape)
         per_block_max = np.abs(np.asarray(x)).max()
         assert np.abs(np.asarray(x - y)).max() <= per_block_max / 127.0 + 1e-6
 
@@ -329,7 +378,7 @@ class TestCompression:
         for _ in range(20):
             g = x + resid
             q, s = compress.quantize_int8(g, block=64)
-            dq = compress.dequantize_int8(q, s, g.shape, block=64)
+            dq = compress.dequantize_int8(q, s, g.shape)
             sent_sum = sent_sum + dq
             resid = g - dq
         drift = np.abs(np.asarray(sent_sum - 20 * x)).max()

@@ -498,6 +498,34 @@ class TestTrainerControllerPlane:
         assert fired and fired[0].rule == "straggler->mitigation"
         assert all(l == l for l in (float(m["loss"]) for m in hist))  # finite
 
+    def test_attached_trainer_publishes_its_steps_to_the_fleet(self):
+        """``attach_fleet`` puts the trainer on the fleet signal plane: after
+        two steps a ``FleetAggregator`` over its store sees one member, and
+        the member's straggler view (host1 reported 3x slow)."""
+        import jax
+        from repro import compat
+        from repro.configs import get_smoke_config
+        from repro.configs.base import ShapeConfig
+        from repro.data.synthetic import batches_for
+        from repro.fleet import FleetAggregator
+        from repro.launch.mesh import make_test_mesh
+        from repro.train.trainer import HostSpec, ReconfigurableTrainer
+
+        cfg = get_smoke_config("llama3.2-1b")
+        shape = ShapeConfig("fleet-test", 64, 4, "train")
+        mesh = make_test_mesh((2, 1), ("pod", "model"))
+        with compat.use_mesh(mesh):
+            tr = ReconfigurableTrainer(cfg, shape, mesh, transport="xla",
+                                       hosts=[HostSpec(0, ["xla"]), HostSpec(1, ["xla"])])
+            tr.attach_fleet(fleet_id="trainfleet", period_s=0.0)
+            agg = FleetAggregator(tr.store, "trainfleet", ttl_s=600.0)
+            state = tr.init_state(jax.random.PRNGKey(0))
+            tr.run(state, batches_for(cfg, shape), 2,
+                   pod_times=lambda i, dt: {"host0": dt, "host1": 3.0 * dt})
+        view = agg.aggregate()
+        assert view["fleet.members"] == 1
+        assert view["fleet.straggler_ratio"] > 2.0
+
     def test_policy_cannot_override_peer_negotiation(self):
         # a transition target outside a PEER's offer set must abort at the
         # rendezvous vote (the proposer consents by proposing; peers veto)

@@ -247,6 +247,82 @@ class TestReconfiguration:
         assert handle.stats.switches == 1
         assert sent["n"] == calls_a["n"] + calls_b["n"]  # nothing lost/duplicated
 
+    @pytest.mark.parametrize("cls", [LockedConn, BarrierConn])
+    def test_swap_under_three_pumping_threads(self, cls):
+        """Three threads send without pause while the handle swaps: the
+        swap commits once, whatever the mechanism, and both stacks carry
+        traffic (BarrierConn's stop-the-world gathers all three)."""
+        fabric = Fabric()
+        st_a, calls_a = self._echo_stack(fabric, "A")
+        st_b, calls_b = self._echo_stack(fabric, "B")
+        handle = (LockedConn(st_a.preferred()) if cls is LockedConn
+                  else BarrierConn(st_a.preferred(), n_threads=3))
+        stop = threading.Event()
+
+        def pump():
+            while not stop.is_set():
+                handle.send([b"x"])
+
+        threads = [threading.Thread(target=pump) for _ in range(3)]
+        for t in threads:
+            t.start()
+        time.sleep(0.05)
+        ok = handle.reconfigure(st_b.preferred())
+        time.sleep(0.05)
+        stop.set()
+        for t in threads:
+            t.join()
+        assert ok and handle.stats.switches == 1
+        assert calls_a["n"] > 0 and calls_b["n"] > 0
+        assert handle.stack.chunnels[0].name == "B"
+
+    def test_controller_switches_barrier_conn_mid_traffic(self):
+        """The closed loop on the lock-free mechanism: three threads pump a
+        BarrierConn whose SlowPath takes 2 ms a send; the ``latency_slo``
+        policy over live op latency (bound 1 ms) switches it to FastPath
+        itself, once, while the threads keep sending."""
+        from repro.core import CostModel, conn_controller
+
+        caps = CapabilitySet.exact("wire:obj")
+
+        def slow_send(m):
+            time.sleep(2e-3)
+            return m
+
+        slow = FnChunnel(fn_name="SlowPath", caps=caps, on_send=slow_send,
+                         cost=CostModel(op_latency_s=2e-3, switch_blip_s=1e-4))
+        fast = FnChunnel(fn_name="FastPath", caps=caps, on_send=lambda m: m,
+                         cost=CostModel(op_latency_s=1e-4, switch_blip_s=1e-4))
+        ep = Fabric().register("barrier-ctl")
+        stack = make_stack(Select(slow, fast), FabricTransport(ep, "sink"))
+        handle = BarrierConn(stack.preferred(), n_threads=3)
+        ctl = conn_controller(handle, stack, policy="latency_slo",
+                              policy_params={"slo_s": 1e-3, "metric": "op_p95_s",
+                                             "hold": 2},
+                              cooldown_s=0.1)
+        sends = {"SlowPath": 0, "FastPath": 0}
+        stop = threading.Event()
+
+        def pump():
+            while not stop.is_set():
+                name = handle.stack.chunnels[0].name
+                handle.send([b"x"])
+                sends[name] += 1
+
+        threads = [threading.Thread(target=pump) for _ in range(3)]
+        for t in threads:
+            t.start()
+        t_end = time.monotonic() + 0.6
+        while time.monotonic() < t_end:
+            time.sleep(0.03)
+            ctl.tick(handle.telemetry.snapshot())
+        stop.set()
+        for t in threads:
+            t.join()
+        assert handle.stats.switches == 1
+        assert handle.stack.chunnels[0].name == "FastPath"
+        assert sends["SlowPath"] and sends["FastPath"]
+
     def test_coordinate_false_aborts(self):
         fabric = Fabric()
         st_a, _ = self._echo_stack(fabric, "A")

@@ -37,8 +37,6 @@ from repro.kernels.flash_attention import ops as fa_ops
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.comm import compress
 from repro.kernels.quantize import leaf
-from repro.kernels.quantize import ops as q_ops
-from repro.kernels.quantize.ref import dequantize_blocks_ref, quantize_blocks_ref
 from repro.kernels.quantize.quantize import dequantize_blocks, quantize_blocks
 from repro.kernels.ssm_scan import ops as ssm_ops
 from repro.kernels.ssm_scan.ref import ssm_scan_chunk_ref
@@ -67,18 +65,20 @@ class TestQuantizeKernel:
     def test_matches_ref_sweep(self, n_blocks, block):
         x = jax.random.normal(jax.random.PRNGKey(n_blocks), (n_blocks, block)) * 5.0
         q_k, s_k = quantize_blocks(x, block=block, interpret=True)
-        q_r, s_r = quantize_blocks_ref(x, block=block)
+        q_r, s_r = compress.quantize_blocks_int8(x)
         np.testing.assert_array_equal(np.asarray(q_k), np.asarray(q_r))
         np.testing.assert_allclose(np.asarray(s_k), np.asarray(s_r), rtol=1e-6)
         y_k = dequantize_blocks(q_k, s_k, block=block, interpret=True)
-        y_r = dequantize_blocks_ref(q_r, s_r, block=block)
+        y_r = compress.dequantize_blocks_int8(q_r, s_r)
         np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), rtol=1e-6)
 
     @pytest.mark.parametrize("shape", [(1000,), (3, 5, 7), (256, 256)])
     def test_ops_roundtrip_shapes(self, shape):
         x = jax.random.normal(jax.random.PRNGKey(0), shape) * 2.0
-        q, s = q_ops.quantize_int8(x, block=128)
-        y = q_ops.dequantize_int8(q, s, shape, block=128)
+        x2d = compress.to_blocks(x.reshape(-1), 128)
+        q, s = quantize_blocks(x2d, block=128, interpret=True)
+        y = dequantize_blocks(q, s, block=128, interpret=True)
+        y = compress.from_blocks(y, (x.size,)).reshape(shape)
         assert y.shape == shape
         err = np.abs(np.asarray(x) - np.asarray(y))
         assert err.max() <= np.abs(np.asarray(x)).max() / 127.0 + 1e-6
@@ -121,7 +121,7 @@ class TestLeafQuantizeKernel:
         x = g.astype(jnp.float32) + r if feedback else g
         xb = compress.to_blocks(x, 256)
         q, s = compress.quantize_blocks_int8(xb)
-        res = compress.from_blocks(xb - q.astype(jnp.float32) * s[..., None], x.shape)
+        res = compress.from_blocks(xb - compress.dequantize_blocks_int8(q, s), x.shape)
         rows = int(np.prod(x.shape[:-1]))
         return q.reshape(rows, -1), s.reshape(rows, -1), res
 
@@ -131,9 +131,9 @@ class TestLeafQuantizeKernel:
         n = q_all.shape[0]
         qb = q_all.reshape((n,) + shape[:-1] + (-1, 256))
         sb = s_all.reshape((n,) + shape[:-1] + (-1,))
-        total = qb[0].astype(jnp.float32) * sb[0][..., None]
+        total = compress.dequantize_blocks_int8(qb[0], sb[0])
         for i in range(1, n):
-            total = total + qb[i].astype(jnp.float32) * sb[i][..., None]
+            total = total + compress.dequantize_blocks_int8(qb[i], sb[i])
         if mean:
             total = total / n
         return compress.from_blocks(total, shape)

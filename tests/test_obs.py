@@ -66,6 +66,23 @@ class TestTracer:
         assert TRACER.ctx() is None
         assert TRACER.collect() == []
 
+    def test_disabled_fabric_round_trip_records_nothing(self):
+        """The batched hot path behind a disabled tracer: a send_batch /
+        recv_many round trip, lossy and unroutable sends included, puts
+        nothing in the ring; the same traffic enabled does."""
+        def round_trip():
+            fab = Fabric(default_link=LinkModel(loss=0.1), seed=0)
+            a, b = fab.register("ovt-a"), fab.register("ovt-b")
+            a.send_batch("ovt-b", [b"x" * 64] * 64)
+            a.send_batch("nowhere", [b"y" * 64] * 4)
+            b.recv_many([None] * 64, timeout=0.1)
+
+        round_trip()
+        assert TRACER.collect() == []
+        TRACER.enable()
+        round_trip()
+        assert TRACER.collect()
+
     def test_span_nesting_and_trace_id(self):
         TRACER.enable()
         with TRACER.span("outer") as outer:

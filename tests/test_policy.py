@@ -317,6 +317,61 @@ class TestConnControllerPolicyPath:
                             policy="cost_aware")
 
 
+class TestKvLoadAdaptive:
+    def test_offered_load_moves_the_client_to_client_shard_and_back(
+            self, seeded_fabric, virtual_clock):
+        """The §7.3 serving policy, by name from the registry, over a live
+        KV routing stack: offered load past ``high_ops_per_s`` moves the
+        client from ServerRouter to ClientShard, and the drain below
+        ``low_ops_per_s`` moves it back. Rates are on the virtual clock;
+        the requests are real, through backends and the router."""
+        from repro.core.telemetry import ConnTelemetry
+        from repro.serving.router import KVBackend, KVClient, Router, routing_stack
+
+        fabric = seeded_fabric()
+        backends = [KVBackend(fabric, f"kv{i}") for i in range(4)]
+        router = Router(fabric, "router", [b.addr for b in backends])
+        ep = fabric.register("cli")
+        stack = routing_stack(ep, [b.addr for b in backends], router_addr="router",
+                              prefer="server")
+        handle = LockedConn(stack.preferred())  # ServerRouter: the low-load default
+        handle.telemetry = ConnTelemetry(now=virtual_clock)
+        handle.telemetry.bind_reconfig(handle.stats)
+        client = KVClient(fabric, ep, handle)
+        ctl = conn_controller(
+            handle, stack, policy="kv_load_adaptive",
+            policy_params={"high_ops_per_s": 150.0, "low_ops_per_s": 120.0, "hold": 2},
+            cooldown_s=0.2, now=virtual_clock)
+        buf = [None]
+        after = []
+        try:
+            # (offered requests/s, requests, open loop): the high phase sends
+            # fire-and-forget with a blocking request every 25th
+            for rate, n_req, open_loop in ((70.0, 40, False), (450.0, 250, True),
+                                           (55.0, 60, False)):
+                for i in range(n_req):
+                    virtual_clock.advance(1.0 / rate)
+                    if open_loop and i % 25:
+                        handle.send([{"op": "get", "key": f"k{i % 37}", "rid": -1,
+                                      "reply_to": ep.addr}])
+                    else:
+                        client.request("put" if i % 3 == 0 else "get", f"k{i % 37}",
+                                       val=i, timeout=1.0)
+                    if (i + 1) % 10 == 0:
+                        ctl.tick(handle.telemetry.snapshot())
+                while handle.recv(buf, timeout=0.001):
+                    pass  # no stale replies into the next phase
+                after.append(handle.stack.chunnels[0].name)
+        finally:
+            for b in backends:
+                b.close()
+            router.close()
+        assert after == ["ServerRouter", "ClientShard", "ServerRouter"]
+        assert [d.rule for d in ctl.switch_log()] == ["high-load->client-shard",
+                                                      "low-load->server-router"]
+        assert handle.stats.switches == 2
+
+
 class TestScorerInNegotiator:
     def test_negotiator_scores_with_telemetry_without_resetting_window(self):
         from repro.core import ConnTelemetry, ServerNegotiator

@@ -350,6 +350,43 @@ class TestCalibrateFromTraces:
         cal = calibrate_from_traces(recs, apply=False)
         assert cal.stack_blips == {"fp1": pytest.approx(0.01)}
 
+    def test_traced_costs_flip_the_annotated_ranking(self):
+        """Annotations that lie are corrected by traces: "Quick" claims
+        0.1 ms a batch but sleeps 2 ms, "Steady" claims 5 ms but sleeps
+        0.3 ms. Ranked on annotations Quick wins; after nine traced batches
+        each and ``calibrate_from_traces(apply=True)``, Steady does."""
+        import time
+
+        from repro.core.cost import LATENCY_FIRST, rank
+
+        def sleeper(s):
+            def xf(msgs):
+                time.sleep(s)
+                return msgs
+            return xf
+
+        quick = FnChunnel("Quick", on_send_batch=sleeper(0.002),
+                          cost=CostModel(op_latency_s=1e-4))
+        steady = FnChunnel("Steady", on_send_batch=sleeper(0.0003),
+                           cost=CostModel(op_latency_s=5e-3))
+
+        def order():
+            cands = [Candidate("quick-stack", chunnel_cost(quick), "Quick"),
+                     Candidate("steady-stack", chunnel_cost(steady), "Steady")]
+            return [c.label for _u, c in rank(cands, LATENCY_FIRST)]
+
+        try:
+            assert order() == ["Quick", "Steady"]
+            TRACER.enable()
+            for ch in (quick, steady):
+                dp = ch.connect_wrap(None)
+                for _ in range(9):
+                    dp.send([b"x" * 128] * 8)
+            calibrate_from_traces(TRACER.collect(clear=True), min_samples=3, apply=True)
+            assert order() == ["Steady", "Quick"]
+        finally:
+            reset_measured_costs()
+
     def test_apply_installs_measured_override(self):
         ch = FnChunnel("CalTest", cost=CostModel(op_latency_s=1e-6))
         try:
@@ -403,6 +440,80 @@ class TestSLOGuardPolicy:
         # a ScoredTarget re-ranks candidates at fire time
         assert hasattr(burn.target, "resolve") or burn.target not in (
             "fast", "safe")
+
+    def test_guard_switches_before_the_raw_threshold(self):
+        """End to end on a virtual clock: an edge connection's RTT degrades
+        (WAN weather: 4-6 ms and one probe in five timing out at 40 ms).
+        Its telemetry is published, federated with a healthy core member,
+        and judged by an SLO whose bound (3.5 ms) sits far below a raw
+        emergency threshold (12 ms, ``hold=2``). On the same snapshots the
+        ``slo_guard`` burn rule switches the connection to the safe option
+        strictly before the raw rule would fire, the breach is an event and
+        trips the recorder, and the regions stay apart."""
+        import numpy as np
+
+        from repro.chaos import VirtualClock
+        from repro.core import (LockedConn, ReconfigController, Select, above,
+                                conn_controller, make_stack)
+        from repro.core.telemetry import ConnTelemetry
+
+        clock = VirtualClock(0.0)
+        stack = make_stack(Select(FnChunnel("FastWire"), FnChunnel("WanLink")))
+        handle = LockedConn(stack.preferred())
+        handle.telemetry = ConnTelemetry(now=clock)
+        store = KVStore()
+        edge = MetricsRegistry()
+        # peek, do not reset: the controller's snapshot owns the rate window
+        edge.register("conn", lambda: handle.telemetry.snapshot(reset_window=False),
+                      instance="edge-conn")
+        pub = MetricsPublisher(store, "fed", "edge-1", edge, region="edge", now=clock)
+        _member(store, "core-1", "core", {"ops_per_s": 40.0, "rtt_p95_s": 0.0004}, clock)
+        fed = MetricsFederator(store, "fed", ttl_s=5.0, now=clock)
+        recorder = _FakeRecorder()
+        slo_engine = SLOEngine(
+            [SLO("region_latency", "obs.region.edge.conn.rtt_p95_s",
+                 objective=0.95, threshold=0.0035)],
+            fast_window_s=0.15, slow_window_s=0.6, budget_window_s=60.0,
+            recorder=recorder, now=clock)
+        guard = conn_controller(handle, stack, policy="slo_guard",
+                                policy_params={"slo": "region_latency",
+                                               "safe_names": ("WanLink",), "hold": 1},
+                                cooldown_s=0.0, now=clock)
+        raw = ReconfigController(
+            rules=[Rule("raw-threshold", above("rtt_p95_s", 0.012), "WanLink", hold=2)],
+            switch=lambda t: True, current=lambda: "FastWire", cooldown_s=0.0, now=clock)
+        rng = np.random.default_rng(13)
+        guard_tick = raw_tick = None
+        for tick in range(45):
+            clock.advance(0.05)
+            for _ in range(4):
+                if tick < 3:  # a clean baseline: the budget starts intact
+                    rtt = 0.0015
+                elif rng.random() < 0.2:
+                    rtt = 0.04
+                else:
+                    rtt = 0.004 + 0.002 * rng.random()
+                handle.telemetry.record_rtt(rtt)
+            pub.publish()
+            view = fed.view()
+            sigs = slo_engine.observe(view)
+            snap = handle.telemetry.snapshot()
+            snap.update(sigs)
+            if guard.tick(snap).reason == "switched" and guard_tick is None:
+                guard_tick = tick
+            if raw.tick(dict(snap)).fired and raw_tick is None:
+                raw_tick = tick
+            if raw_tick is not None:
+                break
+        assert guard_tick is not None and raw_tick is not None
+        assert guard_tick < raw_tick
+        assert [d.rule for d in guard.switch_log()] == ["slo_guard:region_latency:burn"]
+        assert handle.stack.chunnels[0].name == "WanLink"
+        assert [e["kind"] for e in slo_engine.events][:1] == ["breach"]
+        assert recorder.dumps and recorder.dumps[0][0] == "slo_breach_region_latency"
+        assert view["obs.members"] == 2
+        assert (view["obs.region.edge.conn.rtt_p95_s"]
+                > view["obs.region.core.conn.rtt_p95_s"])
 
     def test_custom_burn_thresholds(self):
         rules = policy_rules("slo_guard", self.ctx(
