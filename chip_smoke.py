@@ -127,7 +127,8 @@ def wire_phase() -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.comm.wire import CompressChunnel, _fused_decode, _fused_encode
+    from repro.comm.wire import (CompressChunnel, _fused_decode, _fused_encode,
+                                 packed_form, split_payload)
     from repro.core.fabric import Fabric
     from repro.core.runtime import FabricTransport
 
@@ -160,28 +161,35 @@ def wire_phase() -> None:
         y = np.pad(np.concatenate([np.asarray(m).reshape(-1) for m in got]),
                    (0, pad)).reshape(-1, BLOCK)
         err = np.abs(y - x2d) / _block_steps(x2d)[:, None]
-        # kernel against the jnp oracle, on the device
+        # kernel against the jnp oracle, on the device, in the form the
+        # path takes at this size
         xd = jnp.asarray(x2d)
-        pk = np.asarray(_fused_encode(xd, block=BLOCK, use_kernel=True))
-        po = np.asarray(_fused_encode(xd, block=BLOCK, use_kernel=False))
-        nq = n_blocks * BLOCK
-        dcode = np.abs(pk[:nq].view(np.int8).astype(np.int32)
-                       - po[:nq].view(np.int8).astype(np.int32)).max()
-        dscale = np.abs(pk[nq:].view(np.float32) / po[nq:].view(np.float32) - 1).max()
-        dk = np.asarray(_fused_decode(jnp.asarray(po), n_blocks=n_blocks,
-                                      block=BLOCK, use_kernel=True))
-        do = np.asarray(_fused_decode(jnp.asarray(po), n_blocks=n_blocks,
-                                      block=BLOCK, use_kernel=False))
+        packed = packed_form(n_blocks)
+
+        def payload(use_kernel):
+            return b"".join([np.asarray(a) for a in _fused_encode(
+                xd, block=BLOCK, use_kernel=use_kernel, packed=packed)])
+
+        pk, po = payload(True), payload(False)
+        (qk, sk), (qo, so) = (split_payload(p, n_blocks, BLOCK) for p in (pk, po))
+        dcode = np.abs(qk.astype(np.int32) - qo.astype(np.int32)).max()
+        dscale = np.abs(sk / so - 1).max()
+        parts = tuple(map(jnp.asarray, (np.frombuffer(po, np.uint8),) if packed else (qo, so)))
+        dk = np.asarray(_fused_decode(parts, n_blocks=n_blocks, block=BLOCK,
+                                      use_kernel=True))
+        do = np.asarray(_fused_decode(parts, n_blocks=n_blocks, block=BLOCK,
+                                      use_kernel=False))
         ddec = np.abs(dk - do).max()
-        enc = _fused_encode.lower(xd, block=BLOCK, use_kernel=True).compile()
-        dec = _fused_decode.lower(jnp.asarray(pk), n_blocks=n_blocks, block=BLOCK,
+        enc = _fused_encode.lower(xd, block=BLOCK, use_kernel=True,
+                                  packed=packed).compile()
+        dec = _fused_decode.lower(parts, n_blocks=n_blocks, block=BLOCK,
                                   use_kernel=True).compile()
         kernel = ("tpu_custom_call" in enc.as_text()
                   and "tpu_custom_call" in dec.as_text())
         log(f"wire batch={batch} total={total}B n_blocks={n_blocks}: "
             f"max|err|/step={err.max():.4f} codes max|kernel-oracle|={dcode} "
             f"scales max rel={dscale:.2e} decode max|kernel-oracle|={ddec:.2e} "
-            f"tpu_custom_call={kernel} send+recv {dt:.4f}s (smoke timing)")
+            f"packed={packed} tpu_custom_call={kernel} send+recv {dt:.4f}s (smoke timing)")
         require(err.max() <= 1.0, "a decoded value is off by more than one step")
         require(dcode <= 1, "kernel codes differ from the oracle by more than 1")
         require(dscale <= 1e-6, "kernel scales differ from the oracle")

@@ -5,10 +5,17 @@ The gradient-compression step chunnel models its int8 wire ratio; this module
 actually SHIPS the compressed bytes over the host fabric. A batch of float
 messages goes to the device in pieces of whole quantization blocks: a large
 message as a view of the caller's array, the small ones between joined
-host-side. The pieces are stacked on the device, then one jitted device
-program fuses quantize → pack-to-bytes (int8 payload + bitcast fp32 scales
-into a single uint8 vector); the receive side runs the inverse unpack →
-dequantize in one program and splits back into per-message arrays.
+host-side. The pieces are stacked on the device and quantized in one jitted
+device program, which returns the int8 codes ``(n_blocks, block)`` and the
+fp32 scales ``(n_blocks,)`` as the kernel lays them out. The payload (codes
+row-major, then the scales little-endian) exists only on the host: the
+sender joins the two downloads into it in one copy, and the receiver takes
+the codes and the scales back out of it as two numpy views, uploads both and
+dequantizes them in one program, then splits the floats into per-message
+arrays. A batch under ``SPLIT_MIN_BLOCKS`` saves a transfer each way
+instead: its payload goes down and up as one byte vector, packed and
+unpacked on the device, where at that size the relayout is cheaper than a
+transfer. The payload's bytes are the same in either form.
 ``use_kernel=True`` routes the quantize/dequantize through the Pallas TPU
 kernels in ``repro.kernels.quantize`` (compiled on TPU, interpreted on the CPU
 platform); ``use_kernel=False`` runs the jnp definition of the format,
@@ -23,7 +30,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
@@ -59,31 +66,56 @@ def _next_blob_id() -> int:
         return next(_BLOB_IDS)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "use_kernel", "interpret"))
-def _fused_encode(x2d: jnp.ndarray, *, block: int, use_kernel: bool,
-                  interpret: Optional[bool] = None) -> jnp.ndarray:
-    """(n_blocks, block) f32 -> one uint8 vector: int8 payload then bitcast
-    fp32 scales. One device program for the whole batch. ``interpret`` is the
-    kernel's (see ``repro.kernels.interpret_mode``)."""
+# From this many blocks a batch's codes and scales go down from the sender's
+# device and up to the receiver's as two arrays, the payload joined and split
+# on the host; below it each side moves the payload as one byte vector packed
+# and unpacked on the device, a transfer fewer each way. TPU v5e, a batch's
+# encode and decode in the mean: two arrays 0.16 ms slower at 46 blocks, even
+# at 1,024, 0.12 ms faster at 2,048 and 0.9 ms (median) at 25,600, where the
+# packed decode relays out the whole payload (PERF.md section 6).
+SPLIT_MIN_BLOCKS = 1024
+
+
+def packed_form(n_blocks: int) -> bool:
+    """Whether a batch of ``n_blocks`` moves between host and device as one
+    byte vector rather than as codes and scales."""
+    return n_blocks < SPLIT_MIN_BLOCKS
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("block", "use_kernel", "packed", "interpret"))
+def _fused_encode(x2d: jnp.ndarray, *, block: int, use_kernel: bool, packed: bool,
+                  interpret: Optional[bool] = None) -> Tuple[jnp.ndarray, ...]:
+    """(n_blocks, block) f32 -> the payload's parts, one device program for
+    the whole batch: the int8 codes, flattened, and the f32 scales
+    (n_blocks,); or, ``packed``, one uint8 vector of the codes then the
+    scales' bytes. ``interpret`` is the kernel's (see
+    ``repro.kernels.interpret_mode``)."""
     if use_kernel:
         q, s = quantize_blocks(x2d, block=block, interpret=interpret)
     else:
         q, s = quantize_blocks_int8(x2d)
+    if not packed:
+        return q.reshape(-1), s
     qb = jax.lax.bitcast_convert_type(q, jnp.uint8).reshape(-1)
     sb = jax.lax.bitcast_convert_type(s, jnp.uint8).reshape(-1)
-    return jnp.concatenate([qb, sb])
+    return (jnp.concatenate([qb, sb]),)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("n_blocks", "block", "use_kernel", "interpret"))
-def _fused_decode(packed: jnp.ndarray, *, n_blocks: int, block: int,
+def _fused_decode(parts: Tuple[jnp.ndarray, ...], *, n_blocks: int, block: int,
                   use_kernel: bool, interpret: Optional[bool] = None) -> jnp.ndarray:
-    """Inverse of ``_fused_encode``: uint8 vector -> flat f32 of length
+    """Inverse of ``_fused_encode``: the codes ``(n_blocks, block)`` and the
+    scales ``(n_blocks,)``, or one packed uint8 vector, -> flat f32 of length
     n_blocks * block, again one device program."""
-    qb = packed[: n_blocks * block].reshape(n_blocks, block)
-    q = jax.lax.bitcast_convert_type(qb, jnp.int8)
-    sb = packed[n_blocks * block:].reshape(n_blocks, 4)
-    s = jax.lax.bitcast_convert_type(sb, jnp.float32)
+    if len(parts) == 2:
+        q, s = parts
+    else:
+        (packed,) = parts
+        nq = n_blocks * block
+        q = jax.lax.bitcast_convert_type(packed[:nq].reshape(n_blocks, block), jnp.int8)
+        s = jax.lax.bitcast_convert_type(packed[nq:].reshape(n_blocks, 4), jnp.float32)
     if use_kernel:
         out = dequantize_blocks(q, s, block=block, interpret=interpret)
     else:
@@ -182,13 +214,18 @@ def encode_batch(msgs: List[Any], *, block: int = 256, use_kernel: bool = True,
             xs = [jnp.asarray(p) for p in pieces]
         with TRACER.stage("wire.encode.kernel"):
             x = xs[0] if len(xs) == 1 else _stack_rows(*xs)
-            packed = _fused_encode(x, block=block, use_kernel=use_kernel)
-            # queue the copy back behind the program, as np.asarray alone
+            packed = packed_form(x.shape[0])
+            with TRACER.stage("wire.encode.vector" if packed else "wire.encode.arrays"):
+                parts = _fused_encode(x, block=block, use_kernel=use_kernel,
+                                      packed=packed)
+            # queue the copies back behind the program, as np.asarray alone
             # would, so that waiting here costs no extra round trip
-            packed.copy_to_host_async()
-            packed.block_until_ready()
+            for a in parts:
+                a.copy_to_host_async()
+            jax.block_until_ready(parts)
         with TRACER.stage("wire.encode.d2h"):
-            payload = np.asarray(packed, dtype=np.uint8).tobytes()
+            # the payload, codes then scales, in one host copy
+            payload = b"".join([np.asarray(a) for a in parts])
         n_blocks = x.shape[0]
     else:
         payload = b""
@@ -199,6 +236,14 @@ def encode_batch(msgs: List[Any], *, block: int = 256, use_kernel: bool = True,
         return chunk_payload(payload, hdr, chunk_bytes=chunk_bytes)
 
 
+def split_payload(payload: bytes, n_blocks: int, block: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The codes ``(n_blocks, block)`` int8 and the scales ``(n_blocks,)``
+    f32 of a payload, as views of it."""
+    nq = n_blocks * block
+    codes = np.frombuffer(payload, np.int8, count=nq).reshape(n_blocks, block)
+    return codes, np.frombuffer(payload, "<f4", count=n_blocks, offset=nq)
+
+
 def decode_blob(payload: bytes, hdr: dict, *, use_kernel: bool = True) -> List[np.ndarray]:
     """Reassembled payload + header -> the original batch (dequantized), in
     ``wire.decode.*`` stages."""
@@ -206,9 +251,11 @@ def decode_blob(payload: bytes, hdr: dict, *, use_kernel: bool = True) -> List[n
     n_blocks, block = hdr["n_blocks"], hdr["block"]
     if n_blocks:
         with TRACER.stage("wire.decode.h2d"):
-            packed = jnp.asarray(np.frombuffer(payload, dtype=np.uint8))
+            parts = jax.device_put(
+                (np.frombuffer(payload, np.uint8),) if packed_form(n_blocks)
+                else split_payload(payload, n_blocks, block))
         with TRACER.stage("wire.decode.kernel"):
-            decoded = _fused_decode(packed, n_blocks=n_blocks, block=block,
+            decoded = _fused_decode(parts, n_blocks=n_blocks, block=block,
                                     use_kernel=use_kernel)
             decoded.copy_to_host_async()
             decoded.block_until_ready()

@@ -650,8 +650,8 @@ class TestFusedWire:
         flat = np.concatenate([np.asarray(m, np.float32).reshape(-1) for m in msgs]
                               + [np.zeros(0, np.float32)])
         flat = np.pad(flat, (0, (-flat.size) % 256))
-        want = (np.asarray(wire._fused_encode(jnp.asarray(flat.reshape(-1, 256)),
-                                              block=256, use_kernel=False)).tobytes()
+        want = (np.asarray(wire._fused_encode(jnp.asarray(flat.reshape(-1, 256)), block=256,
+                                              use_kernel=False, packed=True)[0]).tobytes()
                 if flat.size else b"")
         frames = wire.encode_batch(msgs, block=256, use_kernel=False)
         assert b"".join(f["data"] for f in frames) == want
@@ -707,3 +707,37 @@ class TestFusedWire:
         assert names[:2] == ["wire.encode.pack", f"wire.encode.{self.CASES[case]}"]
         pack, inner = spans[0], spans[1]
         assert pack["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] <= pack["ts"] + pack["dur"]
+
+    @pytest.mark.parametrize("size", ["small_tensors", "one_under", "at_split"])
+    def test_batch_opens_arrays_or_vector_inside_kernel(self, size):
+        """A batch of `SPLIT_MIN_BLOCKS` blocks or more opens
+        `wire.encode.arrays` inside `wire.encode.kernel` (codes and scales
+        come down apart), a smaller one `wire.encode.vector` (one packed
+        byte vector); both round-trip."""
+        from repro.comm import wire
+        from repro.obs.trace import TRACER
+
+        n_blocks = {"small_tensors": 46, "one_under": wire.SPLIT_MIN_BLOCKS - 1,
+                    "at_split": wire.SPLIT_MIN_BLOCKS}[size]
+        msgs = [np.random.default_rng(n_blocks).standard_normal(n_blocks * 256)
+                .astype(np.float32)]
+        TRACER.reset()
+        TRACER.enable()
+        try:
+            frames = wire.encode_batch(msgs, block=256, use_kernel=False)
+            spans = [r for r in TRACER.collect() if r["kind"] == "span"]
+        finally:
+            TRACER.disable()
+            TRACER.reset()
+        names = [r["name"] for r in spans]
+        form = "vector" if n_blocks < wire.SPLIT_MIN_BLOCKS else "arrays"
+        i = names.index("wire.encode.kernel")
+        assert names[i + 1] == f"wire.encode.{form}"
+        assert {"wire.encode.vector", "wire.encode.arrays"} & set(names) == {f"wire.encode.{form}"}
+        kernel, inner = spans[i], spans[i + 1]
+        assert kernel["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] <= kernel["ts"] + kernel["dur"]
+        got = wire.decode_blob(b"".join(f["data"] for f in frames), frames[0]["hdr"],
+                               use_kernel=False)
+        amax = np.abs(msgs[0].reshape(n_blocks, 256)).max(axis=1, keepdims=True)
+        err = np.abs(got[0] - msgs[0]).reshape(n_blocks, 256)
+        assert np.all(err <= amax / 254.0 + 1e-6)

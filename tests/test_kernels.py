@@ -287,10 +287,33 @@ class TestSsmScanKernel:
                                    np.asarray(h0 + bx.sum(axis=1)), atol=1e-5)
 
 
+def _wire_payload(x, block, use_kernel, packed):
+    """The payload bytes of ``comm.wire._fused_encode``'s parts, joined as
+    ``encode_batch`` joins them."""
+    from repro.comm import wire
+
+    parts = wire._fused_encode(x, block=block, use_kernel=use_kernel, packed=packed)
+    assert len(parts) == (1 if packed else 2)
+    return b"".join([np.asarray(a) for a in parts])
+
+
+def _wire_decode(payload, n_blocks, block, use_kernel, packed):
+    """``comm.wire._fused_decode`` of a payload, uploaded as ``decode_blob``
+    uploads it in either form."""
+    from repro.comm import wire
+
+    parts = ((np.frombuffer(payload, np.uint8),) if packed
+             else wire.split_payload(payload, n_blocks, block))
+    return np.asarray(wire._fused_decode(tuple(map(jnp.asarray, parts)), n_blocks=n_blocks,
+                                         block=block, use_kernel=use_kernel))
+
+
 class TestFusedWirePath:
-    """comm.wire fuses quantize -> pack-to-bytes -> chunk into one jitted
-    device call; in interpret mode the Pallas kernel path must be
-    byte-identical to the jnp oracle (tier-1 acceptance for ISSUE 7)."""
+    """comm.wire quantizes a batch in one jitted device program whose
+    outputs are the payload's parts: the int8 codes and the f32 scales as
+    two arrays, or, below ``SPLIT_MIN_BLOCKS``, one byte vector packed on
+    the device. In interpret mode the Pallas kernel path must be
+    byte-identical to the jnp oracle, in both forms."""
 
     @pytest.mark.parametrize("block", [64, 128, 256])
     @pytest.mark.parametrize("n_blocks", [1, 3, 8])
@@ -299,50 +322,102 @@ class TestFusedWirePath:
 
         x = jax.random.normal(jax.random.PRNGKey(block + n_blocks),
                               (n_blocks, block)).astype(jnp.float32) * 5.0
-        pk = np.asarray(wire._fused_encode(x, block=block, use_kernel=True))
-        po = np.asarray(wire._fused_encode(x, block=block, use_kernel=False))
-        np.testing.assert_array_equal(pk, po)
+        for packed in (False, True):
+            pk = wire._fused_encode(x, block=block, use_kernel=True, packed=packed)
+            po = wire._fused_encode(x, block=block, use_kernel=False, packed=packed)
+            for a, b in zip(pk, po, strict=True):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert (_wire_payload(x, block, True, packed=False)
+                == _wire_payload(x, block, True, packed=True))
 
     @pytest.mark.parametrize("block", [64, 256])
     def test_decode_kernel_equals_oracle(self, block):
-        from repro.comm import wire
-
         n_blocks = 4
         x = jax.random.normal(jax.random.PRNGKey(9),
                               (n_blocks, block)).astype(jnp.float32)
-        packed = wire._fused_encode(x, block=block, use_kernel=False)
-        dk = np.asarray(wire._fused_decode(packed, n_blocks=n_blocks,
-                                           block=block, use_kernel=True))
-        do = np.asarray(wire._fused_decode(packed, n_blocks=n_blocks,
-                                           block=block, use_kernel=False))
-        np.testing.assert_array_equal(dk, do)
+        payload = _wire_payload(x, block, False, packed=False)
+        for packed in (False, True):
+            dk = _wire_decode(payload, n_blocks, block, True, packed)
+            do = _wire_decode(payload, n_blocks, block, False, packed)
+            np.testing.assert_array_equal(dk, do)
 
     def test_roundtrip_error_bound(self):
         """Wire roundtrip matches the standalone block-quantization error:
-        per-block max abs error <= scale/2 = amax/254."""
-        from repro.comm import wire
-
+        per-block max abs error <= scale/2 = amax/254, in either form."""
         block = 128
         x = jax.random.normal(jax.random.PRNGKey(5), (4, block)) * 3.0
         x = x.astype(jnp.float32)
-        packed = wire._fused_encode(x, block=block, use_kernel=True)
-        y = np.asarray(wire._fused_decode(packed, n_blocks=4, block=block,
-                                          use_kernel=True))
         xb = np.asarray(x).reshape(4, block)
         amax = np.abs(xb).max(axis=1)
-        err = np.abs(xb - y.reshape(4, block)).max(axis=1)
-        assert np.all(err <= amax / 254.0 + 1e-7)
+        for packed in (False, True):
+            payload = _wire_payload(x, block, True, packed)
+            y = _wire_decode(payload, 4, block, True, packed)
+            err = np.abs(xb - y.reshape(4, block)).max(axis=1)
+            assert np.all(err <= amax / 254.0 + 1e-7)
 
     def test_packed_layout(self):
-        """Packed blob = int8 codes then float32 scales as raw bytes."""
+        """Payload = int8 codes then float32 scales as raw bytes: the two
+        arrays as the kernel leaves them, or one uint8 vector of both."""
         from repro.comm import wire
 
         block, n_blocks = 64, 2
         x = jnp.ones((n_blocks, block), jnp.float32)
-        packed = np.asarray(wire._fused_encode(x, block=block, use_kernel=True))
-        assert packed.dtype == np.uint8
-        assert packed.shape == (n_blocks * block + 4 * n_blocks,)
-        codes = packed[: n_blocks * block].view(np.int8)
-        scales = packed[n_blocks * block :].view(np.float32)
+        codes, scales = map(np.asarray, wire._fused_encode(x, block=block, use_kernel=True,
+                                                           packed=False))
+        assert codes.dtype == np.int8 and codes.shape == (n_blocks * block,)
+        assert scales.dtype == np.float32 and scales.shape == (n_blocks,)
         np.testing.assert_array_equal(codes, np.full(n_blocks * block, 127, np.int8))
         np.testing.assert_allclose(scales, np.full(n_blocks, 1.0 / 127.0), rtol=1e-6)
+        (packed,) = wire._fused_encode(x, block=block, use_kernel=True, packed=True)
+        packed = np.asarray(packed)
+        assert packed.dtype == np.uint8
+        assert packed.shape == (n_blocks * block + 4 * n_blocks,)
+        assert packed.tobytes() == codes.tobytes() + scales.tobytes()
+
+    @staticmethod
+    def _batch(case):
+        """Batches as the wire path meets them, at block 256."""
+        from repro.comm import wire
+
+        big = wire.VIEW_MIN_BYTES // 4
+        rng = np.random.default_rng(17)
+        sizes = {
+            "one_block": [256],
+            # a qwen2-7b layer's norm and bias gradients: 46 blocks
+            "small_tensors": [3584, 512, 512, 3584, 3584],
+            "tail_padded": [33, 72, 301],
+            # six pieces stacked on the device, past SPLIT_MIN_BLOCKS
+            "stacked_pieces": [big, 512, big, big, big, 7],
+        }[case]
+        return [rng.standard_normal(n).astype(np.float32) * 3.0 for n in sizes]
+
+    @pytest.mark.parametrize("use_kernel", [True, False])
+    @pytest.mark.parametrize("case", ["one_block", "small_tensors", "tail_padded",
+                                      "stacked_pieces"])
+    def test_payload_is_the_format_golden(self, case, use_kernel):
+        """The joined payload of ``encode_batch`` is the codes' bytes then
+        the scales' little-endian bytes of ``compress.quantize_blocks_int8``
+        over the zero-padded batch, whichever form moved it; ``decode_blob``
+        gives every value back within half a step of its block. The format
+        is compiled, as the wire compiles it: XLA may turn ``amax / 127`` into
+        a product, one ulp off the eager quotient."""
+        from repro.comm import wire
+        from repro.comm.compress import quantize_blocks_int8
+
+        block = 256
+        msgs = self._batch(case)
+        flat = np.concatenate(msgs)
+        x2d = np.pad(flat, (0, (-flat.size) % block)).reshape(-1, block)
+        codes, scales = map(np.asarray, jax.jit(quantize_blocks_int8)(jnp.asarray(x2d)))
+        frames = wire.encode_batch(msgs, block=block, use_kernel=use_kernel)
+        payload = b"".join(f["data"] for f in frames)
+        assert payload == codes.tobytes() + scales.astype("<f4").tobytes()
+        hdr = frames[0]["hdr"]
+        assert hdr["n_blocks"] == x2d.shape[0]
+        if case == "stacked_pieces":
+            assert not wire.packed_form(hdr["n_blocks"])
+            assert len(wire.plan_pieces([m.size for m in msgs], block=block)) == 6
+        got = wire.decode_blob(payload, hdr, use_kernel=use_kernel)
+        y = np.pad(np.concatenate(got), (0, x2d.size - flat.size)).reshape(x2d.shape)
+        steps = np.abs(y - x2d) / scales[:, None]
+        assert steps.max() <= 0.5 + 2.0 ** -14

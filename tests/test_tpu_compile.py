@@ -11,6 +11,7 @@ import time would make the test workers collect different tests.
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -76,16 +77,23 @@ def _dequantize(n_blocks):
     return lower
 
 
-def _wire_encode(sh):
-    return wire._fused_encode.lower(_spec((WIRE_BLOCKS, BLOCK), jnp.float32, sh),
+def _wire_parts(n_blocks, sh):
+    """The decode program's operands, in the form the path takes at
+    ``n_blocks``."""
+    if wire.packed_form(n_blocks):
+        return (_spec((n_blocks * BLOCK + 4 * n_blocks,), jnp.uint8, sh),)
+    return _spec((n_blocks, BLOCK), jnp.int8, sh), _spec((n_blocks,), jnp.float32, sh)
+
+
+def _wire_encode(sh, n_blocks=WIRE_BLOCKS):
+    return wire._fused_encode.lower(_spec((n_blocks, BLOCK), jnp.float32, sh), block=BLOCK,
+                                    use_kernel=True, packed=wire.packed_form(n_blocks),
+                                    interpret=False)
+
+
+def _wire_decode(sh, n_blocks=WIRE_BLOCKS):
+    return wire._fused_decode.lower(_wire_parts(n_blocks, sh), n_blocks=n_blocks,
                                     block=BLOCK, use_kernel=True, interpret=False)
-
-
-def _wire_decode(sh):
-    packed = WIRE_BLOCKS * BLOCK + 4 * WIRE_BLOCKS
-    return wire._fused_decode.lower(_spec((packed,), jnp.uint8, sh),
-                                    n_blocks=WIRE_BLOCKS, block=BLOCK,
-                                    use_kernel=True, interpret=False)
 
 
 def _flash_llama(sh):
@@ -135,6 +143,28 @@ CASES = {
 def test_kernel_compiles_for_v5e(case, one_chip, no_persistent_cache):
     compiled = CASES[case](one_chip).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+DDP_BLOCKS = 25600  # a 25 MiB DDP bucket of floats in blocks of 256
+
+
+def test_wire_programs_hold_codes_and_scales_not_the_payload(one_chip, no_persistent_cache):
+    """At a DDP bucket's 25,600 blocks the wire's device programs hold the
+    codes and the scales as two arrays: the decode program reshapes no 8-bit
+    array into more than ``n_blocks`` rows of 4 bytes, neither program holds
+    an array of the whole payload's length, and the encode program joins no
+    byte vectors."""
+    n = DDP_BLOCKS
+    payload_len = n * BLOCK + 4 * n
+    enc_lowered = _wire_encode(one_chip, n)
+    enc = enc_lowered.compile().as_text()
+    dec = _wire_decode(one_chip, n).compile().as_text()
+    assert "tpu_custom_call" in enc and "tpu_custom_call" in dec
+    rows_of_4 = [int(r) for r in re.findall(r"\b[su]8\[(\d+),4\]", dec)]
+    assert all(r <= n for r in rows_of_4), rows_of_4
+    for text in (enc, dec):
+        assert not re.search(rf"\b[su]8\[{payload_len}\]", text)
+    assert "concatenate" not in enc_lowered.as_text()
 
 
 def test_pods_step_lowers_one_kernel_pair_per_leaf_shape(topo, no_persistent_cache, monkeypatch):
